@@ -47,6 +47,7 @@ from repro.dist import robust_reduce as RR
 from repro.dist.consensus import ConsensusConfig, aggregate_stacked_consensus, \
     consensus_aggregate
 from repro.dist.faults import FaultPlan
+from repro.launch.mesh import make_mesh
 
 N_WORKERS = 8
 DROPOUTS = (0.0, 0.05, 0.1, 0.2, 0.3, 0.5)
@@ -67,7 +68,7 @@ def _timed(fn, *args, iters=20):
 
 def backend_comparison(C=1 << 16, iters=20):
     """Jitted wall time + analytic bytes for both backends, same wire."""
-    mesh = jax.make_mesh((N_WORKERS, 1), ("data", "model"))
+    mesh = make_mesh((N_WORKERS, 1), ("data", "model"))
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (N_WORKERS, C))}
     gp = {"w": jax.device_put(g["w"],
                               NamedSharding(mesh, P("data", None)))}

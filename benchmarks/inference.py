@@ -39,6 +39,7 @@ if __package__ in (None, ""):
 import jax
 
 from repro.infer import coverage_run
+from repro.launch.mesh import make_mesh
 
 ATTACKS = ("gaussian", "signflip", "wrong_value")
 ALPHAS = (0.05, 0.1, 0.2)
@@ -102,7 +103,7 @@ def main(argv=None):
     mesh = None
     n_dev = len(jax.devices())
     if not args.no_mesh and n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         print(f"sharding replications over {n_dev} local devices")
 
     if args.smoke:

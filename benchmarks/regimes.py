@@ -170,11 +170,12 @@ def run_train_wire(attacks, arms, steps, verbose=True):
     from repro.configs import get as get_arch
     from repro.data import lm_batch, shard_batch
     from repro.dist import sharding as S
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.train.step import make_train_step
 
     n = len(jax.devices())
-    mesh = jax.make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
+    mesh = make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
     cfg = get_arch("qwen3-1.7b").reduced()
     rows = {}
     for attack in attacks:
@@ -295,10 +296,12 @@ def main(argv=None):
 
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     mesh = None
     n_dev = len(jax.devices())
     if not args.no_mesh and n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         print(f"sharding coverage replications over {n_dev} devices")
 
     if args.smoke:

@@ -1,7 +1,8 @@
 """Serving example: thin CLI over the ``repro.serve`` engine.
 
-Runs a reduced variant of any assigned architecture on host devices,
-prefills a batch of prompts and decodes continuations in one fused
+Runs any assigned architecture at its published widths (``--reduced``
+for the smoke-scale variant) on the local devices, prefills a batch of
+prompts and decodes continuations in one fused
 scan dispatch. Compile time is reported separately from steady-state
 throughput (the first call of each jitted program pays tracing + XLA
 compilation; timing it together with decode used to overstate the
@@ -13,15 +14,11 @@ metric names ``benchmarks/serve.py`` records — ``serve.compile_s``,
 appends the registry snapshot as telemetry JSONL for
 ``scripts/metrics_dump.py``.
 
-  PYTHONPATH=src python examples/serve.py --arch mixtral-8x7b --tokens 16
-  PYTHONPATH=src python examples/serve.py --robust --attack signflip
-  PYTHONPATH=src python examples/serve.py --scheduler --requests 6
+  PYTHONPATH=src python examples/serve.py --reduced --arch mixtral-8x7b --tokens 16
+  PYTHONPATH=src python examples/serve.py --reduced --robust --attack signflip
+  PYTHONPATH=src python examples/serve.py --reduced --scheduler --requests 6
+  PYTHONPATH=src python examples/serve.py --scheduler --slots 8   # on a chip
 """
-import os
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-
 import argparse
 
 import jax
@@ -29,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get as get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import JsonlSink, MetricsRegistry
 from repro.obs.metrics import now
 from repro.serve import (GREEDY, Request, RobustDecodeConfig, Sampling,
@@ -127,6 +125,8 @@ def run_scheduler(engine, cfg, args, sampling, reg):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the smoke-scale variant of the arch")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--tokens", type=int, default=16)
@@ -153,8 +153,11 @@ def main():
                          "telemetry JSONL (obs.sinks wire format)")
     args = ap.parse_args()
 
-    cfg = get_arch(args.arch).reduced()
-    params = M.init(jax.random.PRNGKey(0), cfg)
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    params = jax.jit(M.init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
 
     sampling = GREEDY
     if args.top_k:

@@ -24,6 +24,7 @@ from repro.configs import get as get_arch
 from repro.data import lm_batch, shard_batch
 from repro.dist import sharding as S
 from repro.dist.faults import FaultPlan
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.obs import JsonlSink, MetricsRegistry
 from repro.obs.metrics import now
@@ -163,14 +164,14 @@ def main():
         # Consensus validity needs n_workers > 5f: put every device on
         # the worker axis (8 > 5), and keep the Byzantine count at 1
         # (f = 1) — floor(0.15 * 7) = 1.
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
         if int(args.byzantine * (n - 1)) > 1:
             print(f"consensus backend: clamping --byzantine "
                   f"{args.byzantine} -> 0.15 (n={n} workers supports "
                   f"f=1)")
             args.byzantine = 0.15
     else:
-        mesh = jax.make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
+        mesh = make_mesh((max(n // 2, 1), min(2, n)), ("data", "model"))
     cfg = build_cfg(args.dmodel, args.layers, vocab=args.vocab)
     n_params = sum(x.size for x in jax.tree.leaves(M.abstract_init(cfg)))
     print(f"model {cfg.name}: {n_params/1e6:.1f}M params, mesh "

@@ -50,7 +50,6 @@ from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.estimator import Estimator
@@ -266,6 +265,13 @@ def consensus_iterate(stack, est: EstimatorLike = "vrmom", *,
     pin = None if pin_mask is None else jnp.asarray(pin_mask)
     hist0 = (jnp.broadcast_to(v0, (k,) + v0.shape) if k
              else jnp.zeros((0,) + v0.shape, jnp.float32))
+    fixed = None
+    if plan.trivial and trim == "mean" and pin is None:
+        # Round 1 leaves every row at the Estimator output; each later
+        # round aggregates n identical rows, which is that value again
+        # in exact arithmetic. Reuse it: a float mean re-summed over n
+        # equal rows can move by an ULP.
+        fixed = jnp.broadcast_to(est.apply(v0, axis=0)[None], v0.shape)
 
     def body(p, carry):
         v, hist, spreads, dropped, q_sum, _last_q = carry
@@ -274,7 +280,9 @@ def consensus_iterate(stack, est: EstimatorLike = "vrmom", *,
             sent = jnp.where(pin[:, None], v0, sent)
         rv = _round_view(plan, key, n, p, quorum)
         honest = rv.alive if pin is None else rv.alive & ~pin
-        if plan.trivial and trim == "mean":
+        if fixed is not None:
+            new = fixed
+        elif plan.trivial and trim == "mean":
             new = jnp.broadcast_to(est.apply(sent, axis=0)[None], v.shape)
         else:
             new = jax.vmap(
@@ -423,6 +431,12 @@ def aggregate_stacked_consensus(grads, mesh, worker_axes,
             return jax.lax.all_gather(sent, worker_axes, axis=0,
                                       tiled=False).reshape(nw, -1)
 
+        # fault-free mean rounds after the first are an exact fixed
+        # point: reuse round 1's value (see consensus_iterate)
+        fixed = (est.apply(exchange(v0), axis=0)
+                 if plan.trivial and trim == "mean" and not has_pin
+                 else None)
+
         def body(p, carry):
             v, hist, spreads, dropped, q_sum, _last_q = carry
             sent = jnp.where(strag[rank], hist[k - 1], v) if k else v
@@ -431,7 +445,9 @@ def aggregate_stacked_consensus(grads, mesh, worker_axes,
             allv = exchange(sent)
             rv = _round_view(plan, key_arg, nw, p, quorum)
             honest = rv.alive & ~pin if has_pin else rv.alive
-            if plan.trivial and trim == "mean":
+            if fixed is not None:
+                new = fixed
+            elif plan.trivial and trim == "mean":
                 new = est.apply(allv, axis=0)
             else:
                 new = _masked_trim(allv, rv.recv[rank], f, trim)
@@ -483,10 +499,10 @@ def aggregate_stacked_consensus(grads, mesh, worker_axes,
             off += size
         return tuple(outs) + (aux,)
 
-    results = shard_map(
+    results = jax.shard_map(
         local_consensus, mesh=mesh,
         in_specs=(P(None), P(None)) + tuple(in_specs),
         out_specs=tuple(out_specs) + (aux_specs,),
-        check_rep=False)(key, pin_arg, *leaves)
+        check_vma=False)(key, pin_arg, *leaves)
     agg_leaves, aux = results[:-1], results[-1]
     return jax.tree.unflatten(treedef, agg_leaves), aux

@@ -36,6 +36,7 @@ __all__ = [
     "mesh_context",
     "current_mesh",
     "axis_size",
+    "partitioned",
     "constrain",
     "RobustBackwardState",
     "push_robust_backward",
@@ -69,6 +70,16 @@ def axis_size(name: str) -> int:
     if mesh is None or name not in mesh.axis_names:
         return 1
     return int(mesh.shape[name])
+
+
+def partitioned() -> bool:
+    """True while the ambient mesh spans more than one device: the code
+    being traced is partitioned by GSPMD, which cannot partition a
+    Mosaic (Pallas TPU) kernel, so kernel call sites pick their jnp
+    path there. Kernels under a mesh run inside ``shard_map`` instead
+    (the RRS wire), where this context does not decide anything."""
+    mesh = current_mesh()
+    return mesh is not None and mesh.size > 1
 
 
 def _clean_entry(mesh, entry, dim: int):

@@ -44,7 +44,6 @@ from typing import Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.estimator import Estimator
@@ -164,9 +163,9 @@ def aggregate_stacked_rrs(grads, mesh, worker_axes,
             off += size
         return tuple(outs)
 
-    agg_leaves = shard_map(
+    agg_leaves = jax.shard_map(
         local_rrs, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=tuple(out_specs), check_rep=False)(*leaves)
+        out_specs=tuple(out_specs), check_vma=False)(*leaves)
     out = jax.tree.unflatten(treedef, agg_leaves)
     if with_diag:
         return _with_tree_diag(jax.tree.unflatten(treedef, leaves), out)
@@ -223,6 +222,10 @@ def aggregate_stacked_auto(grads, est: EstimatorLike = "vrmom", *,
             return out, aux, _with_tree_diag(grads, out)[1]
         return out, aux
 
+    if est.backend == "auto" and CTX.partitioned():
+        # GSPMD partitions this path and cannot partition the Mosaic
+        # kernel: the fused jnp oracle takes its place (same estimator)
+        est = est._replace(backend="ref" if est.coordinatewise else "jnp")
     if est.adaptive:
         out = _wire_apply(grads, lambda wire: est.apply(wire, axis=0))
     else:

@@ -25,7 +25,6 @@ from typing import NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import rcsl as R
@@ -150,9 +149,9 @@ def coverage_run(
         keys = jax.device_put(keys, NamedSharding(mesh, spec))
         # Independent replications: each shard maps its own key slice;
         # no collectives — the rep axis is embarrassingly parallel.
-        run = shard_map(run_keys, mesh=mesh,
+        run = jax.shard_map(run_keys, mesh=mesh,
                         in_specs=spec, out_specs=(spec, spec, spec),
-                        check_rep=False)
+                        check_vma=False)
         covered, width, err = jax.jit(run)(keys)
     else:
         covered, width, err = jax.jit(run_keys)(keys)

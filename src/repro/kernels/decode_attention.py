@@ -18,7 +18,8 @@ Two layouts of the same online-softmax math (DESIGN.md §8):
   dh]``, K/V ``[blk_k, dh]``. K/V are viewed as ``[B, T, Hkv*dh]`` — a
   free reshape of the serving cache layout ``[B, T, Hkv, dh]`` — so the
   per-kv-head slab is a plain block of the last two dims (lane-aligned
-  for dh in {64, 128}) with no transpose of the cache.
+  for dh in {64, 128}) with no transpose of the cache. Per-row lengths
+  are scalar-prefetched into SMEM.
 * **wide** (interpret mode, host CPU): grid ``(n_batch_blocks,
   n_kv_blocks)`` — kv innermost — with a ``[blk_b, Hkv, G, dh]`` query
   block and ``[blk_b, blk_k, Hkv*dh]`` K/V blocks resident at once,
@@ -30,8 +31,10 @@ Two layouts of the same online-softmax math (DESIGN.md §8):
   (``BENCH_attn.json``).
 
 int8 KV caches pass per-(row, position) ``[B, T]`` f32 scales; both
-layouts fuse the dequant multiply into the K/V block load (the cache
-crosses HBM at 1 byte/element — DESIGN.md §12).
+layouts fuse the dequant into the kernel (the cache crosses HBM at 1
+byte/element — DESIGN.md §12): the wide layout scales the K/V block on
+load, the narrow one scales score and probability columns, which is
+the same product regrouped.
 
 Validity masking is per row: ``kv_len`` may be a scalar (classic batched
 decode) or a per-row ``[B]`` vector (the slot-cache serving path,
@@ -83,7 +86,7 @@ def _kernel_narrow(len_ref, q_ref, k_ref, v_ref, *refs, scale, blk_k, n_k,
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
-    ki = pl.program_id(2)
+    b, ki = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -94,19 +97,22 @@ def _kernel_narrow(len_ref, q_ref, k_ref, v_ref, *refs, scale, blk_k, n_k,
     q = q_ref[0, 0].astype(jnp.float32)  # [G, dh] — the whole query group
     k = k_ref[0].astype(jnp.float32)     # [blk_k, dh] — loaded ONCE per
     v = v_ref[0].astype(jnp.float32)     # kv head, shared by all G rows
-    if has_scale:
-        # int8 KV: per-position dequant fused into the block load
-        # (DESIGN.md §12) — the cache crosses HBM at 1 byte/element
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
     s = jnp.dot(q * scale, k.T, preferred_element_type=jnp.float32)
+    if has_scale:
+        # int8 KV: the per-position dequant scale of key t multiplies
+        # score column t, a [1, blk_k] lane row (DESIGN.md §12)
+        s = s * ks_ref[0]
 
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[b]  # scalar-prefetched per-row length (SMEM)
     k_pos = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(k_pos < kv_len, s, NEG_INF)
-    _online_update(
-        s, lambda p: jnp.dot(p, v, preferred_element_type=jnp.float32),
-        m_scr, l_scr, acc_scr)
+
+    def pv(p):
+        if has_scale:
+            p = p * vs_ref[0]  # value t's dequant scale on column t
+        return jnp.dot(p, v, preferred_element_type=jnp.float32)
+
+    _online_update(s, pv, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_k - 1)
     def _done():
@@ -153,15 +159,102 @@ def _kernel_wide(len_ref, q_ref, k_ref, v_ref, *refs, scale, blk_k, n_k,
         o_ref[...] = out.astype(o_ref.dtype)
 
 
+def _narrow_call(q, k2, v2, lens, k_scale, v_scale, *, scale, blk_k, n_k,
+                 interpret):
+    """Narrow layout: grid (B, Hkv, n_kv_blocks), 2-D MXU-shaped blocks,
+    kv axis sequential. ``lens`` is scalar-prefetched into SMEM (a
+    per-row [1, 1] VMEM block would break the (8, 128) block rule), and
+    the int8 scales ride as [B, 1, Tk], so each row's [1, blk_k] block
+    spans its whole second-minor dim."""
+    B, Hkv, G, dh = q.shape
+    has_scale = k_scale is not None
+    in_specs = [
+        pl.BlockSpec((1, 1, G, dh), lambda b, h, j, lens: (b, h, 0, 0)),
+        pl.BlockSpec((1, blk_k, dh), lambda b, h, j, lens: (b, j, h)),
+        pl.BlockSpec((1, blk_k, dh), lambda b, h, j, lens: (b, j, h)),
+    ]
+    args = (lens, q, k2, v2)
+    if has_scale:
+        in_specs += [pl.BlockSpec((1, 1, blk_k),
+                                  lambda b, h, j, lens: (b, 0, j))] * 2
+        args += (k_scale[:, None], v_scale[:, None])
+    return pl.pallas_call(
+        functools.partial(_kernel_narrow, scale=scale, blk_k=blk_k, n_k=n_k,
+                          has_scale=has_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, n_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, G, dh),
+                                   lambda b, h, j, lens: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
+        interpret=interpret,
+    )(*args)
+
+
+def _wide_call(q, k2, v2, lens, k_scale, v_scale, *, scale, blk_k, n_k,
+               blk_b, interpret):
+    """Wide layout: [blk_b, Hkv, G, dh] query block per grid step, batch
+    blocks outer, kv axis inner (scratch accumulates per batch block).
+    Zero-padded batch rows (lens 0) normalize to 0 and are sliced off."""
+    B, Hkv, G, dh = q.shape
+    has_scale = k_scale is not None
+    blk_b = min(blk_b, B)
+    pad_b = (-B) % blk_b
+    if pad_b:
+        q = jnp.pad(q, ((0, pad_b),) + ((0, 0),) * 3)
+        k2 = jnp.pad(k2, ((0, pad_b), (0, 0), (0, 0)))
+        v2 = jnp.pad(v2, ((0, pad_b), (0, 0), (0, 0)))
+        lens = jnp.pad(lens, (0, pad_b))
+        if has_scale:
+            k_scale = jnp.pad(k_scale, ((0, pad_b), (0, 0)))
+            v_scale = jnp.pad(v_scale, ((0, pad_b), (0, 0)))
+    Bb = B + pad_b
+    in_specs = [
+        pl.BlockSpec((blk_b, 1), lambda i, j: (i, 0)),
+        pl.BlockSpec((blk_b, Hkv, G, dh), lambda i, j: (i, 0, 0, 0)),
+        pl.BlockSpec((blk_b, blk_k, Hkv * dh), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((blk_b, blk_k, Hkv * dh), lambda i, j: (i, j, 0)),
+    ]
+    args = (lens[:, None], q, k2, v2)
+    if has_scale:
+        in_specs += [pl.BlockSpec((blk_b, blk_k), lambda i, j: (i, j))] * 2
+        args += (k_scale, v_scale)
+    out = pl.pallas_call(
+        functools.partial(_kernel_wide, scale=scale, blk_k=blk_k, n_k=n_k,
+                          has_scale=has_scale),
+        grid=(Bb // blk_b, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((blk_b, Hkv, G, dh),
+                               lambda i, j: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bb, Hkv, G, dh), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((blk_b, Hkv, G), jnp.float32),
+            pltpu.VMEM((blk_b, Hkv, G), jnp.float32),
+            pltpu.VMEM((blk_b, Hkv, G, dh), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*args)
+    return out[:B]
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("blk_k", "blk_b", "interpret"))
+                   static_argnames=("blk_k", "blk_b", "interpret", "narrow"))
 def _decode_grouped(q, k, v, lens, k_scale, v_scale, blk_k, blk_b,
-                    interpret):
+                    interpret, narrow=None):
     """q: [B, Hkv, G, dh]; k/v: [B, T, Hkv, dh]; lens: [B] int32;
-    k_scale/v_scale: [B, T] f32 int8-dequant scales or None."""
+    k_scale/v_scale: [B, T] f32 int8-dequant scales or None.
+
+    ``narrow`` picks the layout: None means narrow when compiled and
+    wide when interpreted (tests pass True to run the compiled layout's
+    math in interpret mode)."""
     B, Hkv, G, dh = q.shape
     T = k.shape[1]
-    has_scale = k_scale is not None
     blk_k = min(blk_k, T)
     pad_k = (-T) % blk_k
     if pad_k:
@@ -169,90 +262,19 @@ def _decode_grouped(q, k, v, lens, k_scale, v_scale, blk_k, blk_b,
         padw = ((0, 0), (0, pad_k), (0, 0), (0, 0))
         k = jnp.pad(k, padw)
         v = jnp.pad(v, padw)
-        if has_scale:
+        if k_scale is not None:
             k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k)))
             v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k)))
     Tk = T + pad_k
-    n_k = Tk // blk_k
     # Free reshape: the per-kv-head [blk_k, dh] slab becomes a plain
     # block of the last two dims — the cache is never transposed.
     k2 = k.reshape(B, Tk, Hkv * dh)
     v2 = v.reshape(B, Tk, Hkv * dh)
-    scale = 1.0 / (dh ** 0.5)
-
-    if interpret:
-        # wide layout: [blk_b, Hkv, G, dh] query block per grid step,
-        # batch blocks outer, kv axis inner (scratch accumulates per
-        # batch block). Zero-padded batch rows (lens 0) normalize to 0
-        # and are sliced off below.
-        blk_b = min(blk_b, B)
-        pad_b = (-B) % blk_b
-        if pad_b:
-            q = jnp.pad(q, ((0, pad_b),) + ((0, 0),) * 3)
-            k2 = jnp.pad(k2, ((0, pad_b), (0, 0), (0, 0)))
-            v2 = jnp.pad(v2, ((0, pad_b), (0, 0), (0, 0)))
-            lens = jnp.pad(lens, (0, pad_b))
-            if has_scale:
-                k_scale = jnp.pad(k_scale, ((0, pad_b), (0, 0)))
-                v_scale = jnp.pad(v_scale, ((0, pad_b), (0, 0)))
-        Bb = B + pad_b
-        kernel = functools.partial(_kernel_wide, scale=scale, blk_k=blk_k,
-                                   n_k=n_k, has_scale=has_scale)
-        grid = (Bb // blk_b, n_k)
-        in_specs = [
-            pl.BlockSpec((blk_b, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_b, Hkv, G, dh), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((blk_b, blk_k, Hkv * dh), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((blk_b, blk_k, Hkv * dh), lambda i, j: (i, j, 0)),
-        ]
-        if has_scale:
-            in_specs += [pl.BlockSpec((blk_b, blk_k), lambda i, j: (i, j)),
-                         pl.BlockSpec((blk_b, blk_k), lambda i, j: (i, j))]
-        out_specs = pl.BlockSpec((blk_b, Hkv, G, dh),
-                                 lambda i, j: (i, 0, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((Bb, Hkv, G, dh), q.dtype)
-        scratch = [
-            pltpu.VMEM((blk_b, Hkv, G), jnp.float32),
-            pltpu.VMEM((blk_b, Hkv, G), jnp.float32),
-            pltpu.VMEM((blk_b, Hkv, G, dh), jnp.float32),
-        ]
-    else:
-        # narrow layout: 2-D MXU-shaped blocks, kv axis sequential; the
-        # batch already rides the grid row-by-row (blk_b inapplicable)
-        Bb = B
-        kernel = functools.partial(_kernel_narrow, scale=scale, blk_k=blk_k,
-                                   n_k=n_k, has_scale=has_scale)
-        grid = (B, Hkv, n_k)
-        in_specs = [
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, blk_k, dh), lambda b, h, j: (b, j, h)),
-            pl.BlockSpec((1, blk_k, dh), lambda b, h, j: (b, j, h)),
-        ]
-        if has_scale:
-            in_specs += [pl.BlockSpec((1, blk_k), lambda b, h, j: (b, j)),
-                         pl.BlockSpec((1, blk_k), lambda b, h, j: (b, j))]
-        out_specs = pl.BlockSpec((1, 1, G, dh), lambda b, h, j: (b, h, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype)
-        scratch = [
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, dh), jnp.float32),
-        ]
-
-    args = (lens[:, None], q, k2, v2)
-    if has_scale:
-        args += (k_scale, v_scale)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
-    return out[:B] if Bb != B else out
+    kw = dict(scale=1.0 / (dh ** 0.5), blk_k=blk_k, n_k=Tk // blk_k,
+              interpret=interpret)
+    if narrow if narrow is not None else not interpret:
+        return _narrow_call(q, k2, v2, lens, k_scale, v_scale, **kw)
+    return _wide_call(q, k2, v2, lens, k_scale, v_scale, blk_b=blk_b, **kw)
 
 
 def _default_interpret():

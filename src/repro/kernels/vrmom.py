@@ -18,20 +18,23 @@ TPU adaptation choices (DESIGN.md §6/§7):
 
 * The worker axis m is small and static (replica count or the data/pod
   mesh axes), so order statistics are computed with an **odd-even
-  transposition sorting network** over the sublane axis: m compare-
-  exchange passes of stride-2 slices — no gathers (Pallas TPU has no
-  general gather), no data-dependent control flow, VPU-friendly.
-* Rows are padded to the next even/static size with +inf so the honest
-  order statistics live in the first m slots at *static* indices.
+  transposition sorting network** over the sublane axis: each phase
+  compare-exchanges all row pairs at once against sublane rotations of
+  the block — no gathers (Pallas TPU has no general gather), no strided
+  slices, no data-dependent control flow, VPU-friendly.
+* The order statistics then sit at *static* row indices of the sorted
+  block; sums over rows fold in a fixed row order.
 * Quantile counts use Sum_k 1(z <= Delta_k) with Delta_k baked in as
   compile-time constants (K static), accumulated k-at-a-time to keep the
   VMEM footprint at one [m, C_tile] block.
 
-Grid: 1-D over coordinate tiles; block [m_pad, C_TILE] in VMEM. Batched
-inputs ([m, B, V] logit stacks from the replicated decode path) are
-handled by the entry-point reshape: every estimator is coordinate-wise,
-so trailing dims flatten into the coordinate axis — the serve decode
-``lax.scan`` calls the same kernel the gradient path uses.
+Grid: 1-D over coordinate tiles; block [m, C_TILE] in VMEM, output a
+lane-dense [1, C_TILE] row. Batched inputs ([m, B, V] logit stacks from
+the replicated decode path) are handled by the entry-point reshape:
+every estimator is coordinate-wise, so trailing dims flatten into the
+coordinate axis — the serve decode ``lax.scan`` calls the same kernel
+the gradient path uses. The fused sampling tail reads the stack as
+[B, m, V] so each batch row's worker slab is one [m, C_TILE] block.
 """
 from __future__ import annotations
 
@@ -44,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.vrmom import _MAD_CONST, _deltas_cached, psi_sum
 
-DEFAULT_TILE = 512        # compiled TPU path: [m_pad, 512] block in VMEM
+DEFAULT_TILE = 512        # compiled TPU path: [m, 512] block in VMEM
 INTERPRET_TILE = 65536    # interpret mode: amortize per-grid-step
                           # interpreter overhead (host memory, no VMEM cap)
 
@@ -61,78 +64,91 @@ __all__ = [
 ]
 
 
-def _sort_rows(x, m_pad):
-    """Odd-even transposition sort along axis 0 (ascending), static network."""
-    for p in range(m_pad):
-        if p % 2 == 0:  # even phase: pairs (0,1),(2,3),...
-            a, b = x[0::2], x[1::2]
-            lo, hi = jnp.minimum(a, b), jnp.maximum(a, b)
-            x = jnp.stack([lo, hi], axis=1).reshape(x.shape)
-        else:  # odd phase: pairs (1,2),(3,4),...; first/last rows fixed
-            if m_pad <= 2:
-                continue
-            mid = x[1 : m_pad - 1]
-            a, b = mid[0::2], mid[1::2]
-            lo, hi = jnp.minimum(a, b), jnp.maximum(a, b)
-            mid = jnp.stack([lo, hi], axis=1).reshape(mid.shape)
-            x = jnp.concatenate([x[0:1], mid, x[m_pad - 1 : m_pad]], axis=0)
-    return x
+def _sort_rows(x):
+    """Odd-even transposition sort of ``x [m, C]`` along axis 0 (ascending).
+
+    Each phase compare-exchanges every (even, odd) or (odd, even) row
+    pair at once: the partner rows are sublane rotations of the whole
+    block (``pltpu.roll``), and iota masks pick, per row, whether it
+    keeps the min or the max of its pair. No strided slices and no
+    interleave, so Mosaic lowers every op as a dense vector op. The
+    rotation direction is read off a rotated row iota instead of being
+    assumed. The phases run as a loop of (even, odd) pairs, so the
+    program stays O(1) in m; m or m+1 phases sort m rows exactly.
+    """
+    m = x.shape[0]
+    if m == 1:
+        return x
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    src = pltpu.roll(row, 1, 0)        # source row of roll(x, 1)[i]
+    a_is_next = src == row + 1
+    a_is_prev = src == row - 1
+    has_next, has_prev = row + 1 < m, row >= 1
+    even = row % 2 == 0
+
+    def phase(x, lo, hi):
+        # lo rows pair with row i+1 and keep the min; hi rows pair with
+        # row i-1 and keep the max; the rest pass through
+        a, b = pltpu.roll(x, 1, 0), pltpu.roll(x, m - 1, 0)
+        nxt = jnp.where(a_is_next, a, b)
+        prv = jnp.where(a_is_prev, a, b)
+        return jnp.where(lo, jnp.minimum(x, nxt),
+                         jnp.where(hi, jnp.maximum(x, prv), x))
+
+    def two_phases(_, x):
+        x = phase(x, even & has_next, ~even & has_prev)
+        return phase(x, ~even & has_next, even & has_prev)
+
+    return jax.lax.fori_loop(0, (m + 1) // 2, two_phases, x)
 
 
-def _median_of_sorted(xs, m):
-    return 0.5 * (xs[(m - 1) // 2] + xs[m // 2])
+def _sum_rows(x, lo, hi):
+    """Sum of rows lo..hi-1 of ``x [m, C]`` -> [1, C], folded in row order
+    (a fixed order, so both kernels produce the same bits)."""
+    acc = x[lo : lo + 1]
+    for i in range(lo + 1, hi):
+        acc = acc + x[i : i + 1]
+    return acc
 
 
-def _agg_block(x, *, m, m_pad, method, K, k_trim, eps):
-    """Aggregate one VMEM-resident block over axis 0: [m_pad, ...] -> [...].
+def _agg_block(x, *, method, K, k_trim, eps):
+    """Aggregate one VMEM-resident block over axis 0: [m, C] -> [1, C].
 
     Shared by the plain aggregation kernel and the fused sampling-tail
     kernel — both run the exact same op sequence, so fused greedy tokens
     are bit-identical to argmax over the unfused aggregate.
     """
+    m = x.shape[0]
     if method == "mean":
-        # padded rows are +inf: mask them out instead of sorting
-        row_valid = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < m
-        return jnp.sum(jnp.where(row_valid, x, 0.0), axis=0) / m
-    xs = _sort_rows(x, m_pad)  # +inf padding sorts past the honest rows
+        return _sum_rows(x, 0, m) / m
+    xs = _sort_rows(x)
     if method == "trimmed_mean":
         # rows k_trim..m-k_trim-1 of the already-sorted block: the trim
         # is a static slice, so the trimmed mean costs one extra sum.
-        seg = xs[k_trim : m - k_trim]
-        return jnp.sum(seg, axis=0) / seg.shape[0]
-    med = _median_of_sorted(xs, m)
+        return _sum_rows(xs, k_trim, m - k_trim) / (m - 2 * k_trim)
+    med = 0.5 * (xs[(m - 1) // 2 : (m - 1) // 2 + 1] + xs[m // 2 : m // 2 + 1])
     if method == "median":
         return med
     # vrmom: MAD scale + quantile-count correction, same VMEM block
-    dev = jnp.abs(x - med[None])  # padded rows are +inf already
-    devs = _sort_rows(dev, m_pad)
-    mad = _median_of_sorted(devs, m)
+    devs = _sort_rows(jnp.abs(x - med))
+    mad = 0.5 * (devs[(m - 1) // 2 : (m - 1) // 2 + 1]
+                 + devs[m // 2 : m // 2 + 1])
     s = mad / _MAD_CONST
-    z = (x - med[None]) / jnp.maximum(s, eps)[None]
-    row_valid = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0) < m
+    z = (x - med) / jnp.maximum(s, eps)
     deltas = _deltas_cached(K)
     counts = jnp.zeros_like(z)
     for k in range(K):
         counts = counts + (z <= jnp.float32(deltas[k])).astype(jnp.float32)
-    summand = jnp.where(row_valid, counts - K / 2.0, 0.0)
-    total = jnp.sum(summand, axis=0)
+    # integer-valued summands: the sum is exact in any order
+    total = jnp.sum(counts - K / 2.0, axis=0, keepdims=True)
     out = med - s * total / (m * psi_sum(K))
     return jnp.where(s <= eps, med, out)
 
 
-def _kernel(x_ref, o_ref, *, m, m_pad, method, K, k_trim, eps):
-    x = x_ref[...].astype(jnp.float32)  # [m_pad, C]
-    out = _agg_block(x, m=m, m_pad=m_pad, method=method, K=K,
-                     k_trim=k_trim, eps=eps)
+def _kernel(x_ref, o_ref, *, method, K, k_trim, eps):
+    x = x_ref[...].astype(jnp.float32)  # [m, tile]
+    out = _agg_block(x, method=method, K=K, k_trim=k_trim, eps=eps)
     o_ref[...] = out.astype(o_ref.dtype)
-
-
-def _pad_rows(x, m_pad):
-    m = x.shape[0]
-    if m_pad == m:
-        return x
-    pad = jnp.full((m_pad - m,) + x.shape[1:], jnp.inf, dtype=x.dtype)
-    return jnp.concatenate([x, pad], axis=0)
 
 
 @functools.partial(
@@ -142,44 +158,53 @@ def _pad_rows(x, m_pad):
 def _agg_2d(x, method: str, K: int, k_trim: int, tile: int, interpret: bool,
             eps: float):
     m, c = x.shape
-    m_pad = m + (m % 2)  # sorting network wants an even row count
-    tile = min(tile, max(c, 1))
+    tile = min(tile, -(-max(c, 1) // 128) * 128)  # lane-aligned, <= C
     c_pad = -(-c // tile) * tile
-    xp = _pad_rows(x, m_pad)
     if c_pad != c:
-        xp = jnp.pad(xp, ((0, 0), (0, c_pad - c)), constant_values=1.0)
+        x = jnp.pad(x, ((0, 0), (0, c_pad - c)), constant_values=1.0)
+    # lane-dense [1, C] output: a 2-D block meets the (8, 128) tiling rule
+    # (leading dim equal to the array's) where a 1-D block would have to
+    # match XLA's 1024-element tiling
     out = pl.pallas_call(
-        functools.partial(_kernel, m=m, m_pad=m_pad, method=method, K=K,
-                          k_trim=k_trim, eps=eps),
+        functools.partial(_kernel, method=method, K=K, k_trim=k_trim,
+                          eps=eps),
         grid=(c_pad // tile,),
-        in_specs=[pl.BlockSpec((m_pad, tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((c_pad,), x.dtype),
+        in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, c_pad), x.dtype),
         interpret=interpret,
-    )(xp)
-    return out[:c]
+    )(x)
+    return out[0, :c]
 
 
-def _topk_rows(vals, idxs, k):
-    """Row-wise top-k of (value, index) pairs along axis 1.
+def _topk_rows(cands, k):
+    """Row-wise top-k over the union of ``(values, indices)`` candidate
+    lists, each pair ``[B, n_j]``.
 
     Descending by value, ties broken toward the smaller index — the same
     order ``jax.lax.top_k`` produces — via k static max-extraction
-    passes (no sort, no gather). Returns ([B, k], [B, k])."""
-    tv, ti = [], []
-    for _ in range(k):
-        mx = jnp.max(vals, axis=1, keepdims=True)
-        sel = jnp.min(jnp.where(vals == mx, idxs, _BIG_IDX),
-                      axis=1, keepdims=True)
-        tv.append(mx)
-        ti.append(sel)
-        vals = jnp.where(idxs == sel, _NEG_INF, vals)
-    return jnp.concatenate(tv, axis=1), jnp.concatenate(ti, axis=1)
+    passes (no sort, no gather). Extracted entries are placed into their
+    output lane with a select, so nothing is concatenated along lanes.
+    Returns ([B, k] f32, [B, k] int32)."""
+    vals0 = cands[0][0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (vals0.shape[0], k), 1)
+    tv = jnp.full(lane.shape, _NEG_INF, jnp.float32)
+    ti = jnp.zeros(lane.shape, jnp.int32)
+    for j in range(k):
+        mx = functools.reduce(jnp.maximum, [
+            jnp.max(v, axis=1, keepdims=True) for v, _ in cands])
+        sel = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(v == mx, i, _BIG_IDX), axis=1, keepdims=True)
+            for v, i in cands])
+        tv = jnp.where(lane == j, mx, tv)
+        ti = jnp.where(lane == j, sel, ti)
+        cands = [(jnp.where(i == sel, _NEG_INF, v), i) for v, i in cands]
+    return tv, ti
 
 
-def _tail_kernel(x_ref, *refs, m, m_pad, method, K, k_trim, eps, tile,
-                 v_total, n_vt, top_k, with_agg):
-    """Aggregation + sampling epilogue on one [m_pad, B, tile] block.
+def _tail_kernel(x_ref, *refs, method, K, k_trim, eps, tile, v_total, n_vt,
+                 top_k, with_agg):
+    """Aggregation + sampling epilogue on one [B, m, tile] block.
 
     The aggregate is computed once per vocab tile; the sampling tail
     (running argmax for greedy, running top-k otherwise) reuses the same
@@ -188,13 +213,16 @@ def _tail_kernel(x_ref, *refs, m, m_pad, method, K, k_trim, eps, tile,
     refs = list(refs)
     agg_ref = refs.pop(0) if with_agg else None
     if top_k == 0:
-        tok_ref, bv_scr, bi_scr = refs
+        tok_ref, agg_scr, bv_scr, bi_scr = refs
     else:
-        topv_ref, topi_ref, bv_scr, bi_scr = refs
+        topv_ref, topi_ref, agg_scr, bv_scr, bi_scr = refs
     vi = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)  # [m_pad, B, tile]
-    agg = _agg_block(x, m=m, m_pad=m_pad, method=method, K=K,
-                     k_trim=k_trim, eps=eps)  # [B, tile]
+    # each batch row's [m, tile] worker slab is a leading-dim slice
+    for b in range(x_ref.shape[0]):
+        agg_scr[b : b + 1] = _agg_block(x_ref[b].astype(jnp.float32),
+                                        method=method, K=K, k_trim=k_trim,
+                                        eps=eps)
+    agg = agg_scr[...]  # [B, tile]
     if with_agg:
         agg_ref[...] = agg.astype(agg_ref.dtype)
     # mask the padded tail of the vocab axis so it can never win the
@@ -219,12 +247,9 @@ def _tail_kernel(x_ref, *refs, m, m_pad, method, K, k_trim, eps, tile,
 
         @pl.when(vi == n_vt - 1)
         def _write_tok():
-            tok_ref[...] = bi_scr[:, 0]
+            tok_ref[...] = bi_scr[...]
     else:
-        tv, ti = _topk_rows(a, pos, top_k)
-        mv, mi = _topk_rows(jnp.concatenate([bv_scr[...], tv], axis=1),
-                            jnp.concatenate([bi_scr[...], ti], axis=1),
-                            top_k)
+        mv, mi = _topk_rows([(bv_scr[...], bi_scr[...]), (a, pos)], top_k)
         bv_scr[...] = mv
         bi_scr[...] = mi
 
@@ -242,46 +267,48 @@ def _tail_kernel(x_ref, *refs, m, m_pad, method, K, k_trim, eps, tile,
 def _tail_3d(x, method: str, K: int, k_trim: int, tile: int, interpret: bool,
              eps: float, top_k: int, with_agg: bool):
     m, b, v = x.shape
-    m_pad = m + (m % 2)  # sorting network wants an even row count
     tile = max(min(tile, max(v, 1)), max(top_k, 1))
     v_pad = -(-v // tile) * tile
     n_vt = v_pad // tile
-    xp = _pad_rows(x, m_pad)
+    # [B, m, V]: each batch row's worker stack is one [m, tile] block slab
+    # (the transpose fuses into whatever produced the stack)
+    x = jnp.swapaxes(x, 0, 1)
     if v_pad != v:
-        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, v_pad - v)),
-                     constant_values=1.0)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, v_pad - v)), constant_values=1.0)
     out_shape, out_specs = [], []
     if with_agg:
         out_shape.append(jax.ShapeDtypeStruct((b, v_pad), x.dtype))
         out_specs.append(pl.BlockSpec((b, tile), lambda i: (0, i)))
+    scratch = [pltpu.VMEM((b, tile), jnp.float32)]
     if top_k == 0:
-        out_shape.append(jax.ShapeDtypeStruct((b,), jnp.int32))
-        out_specs.append(pl.BlockSpec((b,), lambda i: (0,)))
-        scratch = [pltpu.VMEM((b, 1), jnp.float32),
-                   pltpu.VMEM((b, 1), jnp.int32)]
+        # [B, 1] token column: a whole-array 2-D block (a 1-D [B] block
+        # would have to match XLA's 1-D tiling)
+        out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec((b, 1), lambda i: (0, 0)))
+        scratch += [pltpu.VMEM((b, 1), jnp.float32),
+                    pltpu.VMEM((b, 1), jnp.int32)]
     else:
         out_shape.append(jax.ShapeDtypeStruct((b, top_k), jnp.float32))
         out_specs.append(pl.BlockSpec((b, top_k), lambda i: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b, top_k), jnp.int32))
         out_specs.append(pl.BlockSpec((b, top_k), lambda i: (0, 0)))
-        scratch = [pltpu.VMEM((b, top_k), jnp.float32),
-                   pltpu.VMEM((b, top_k), jnp.int32)]
+        scratch += [pltpu.VMEM((b, top_k), jnp.float32),
+                    pltpu.VMEM((b, top_k), jnp.int32)]
     outs = pl.pallas_call(
-        functools.partial(_tail_kernel, m=m, m_pad=m_pad, method=method,
-                          K=K, k_trim=k_trim, eps=eps, tile=tile,
-                          v_total=v, n_vt=n_vt, top_k=top_k,
-                          with_agg=with_agg),
+        functools.partial(_tail_kernel, method=method, K=K, k_trim=k_trim,
+                          eps=eps, tile=tile, v_total=v, n_vt=n_vt,
+                          top_k=top_k, with_agg=with_agg),
         grid=(n_vt,),
-        in_specs=[pl.BlockSpec((m_pad, b, tile), lambda i: (0, 0, i))],
+        in_specs=[pl.BlockSpec((b, m, tile), lambda i: (0, 0, i))],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(xp)
+    )(x)
     outs = list(outs)
     agg = outs.pop(0)[:, :v] if with_agg else None
     if top_k == 0:
-        return agg, outs[0]
+        return agg, outs[0][:, 0]
     return agg, outs[0], outs[1]
 
 

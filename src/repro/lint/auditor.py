@@ -71,7 +71,9 @@ def _mesh1d():
     if nd < 2:
         raise _Skip(f"needs >= 2 devices for a worker mesh, have {nd} "
                     f"(run the CLI with --host-devices 8)")
-    return jax.make_mesh((nd,), ("data",)), nd
+    from ..launch.mesh import make_mesh
+
+    return make_mesh((nd,), ("data",)), nd
 
 
 def _expect_raises(thunk, exc, must_contain: str, what: str) -> None:

@@ -13,7 +13,9 @@ config (``ArchConfig.attn_backend``) decides what runs:
   ``kernels/decode_attention`` for single-query cached decode (GQA
   grouped in-kernel, per-row ``kv_len``). Off-TPU both run in interpret
   mode with wide tiles. Calls the kernels cannot express (sliding
-  window, TP > 1 — both are ``mha``-only features) route to ``mha`` —
+  window, TP > 1 — both are ``mha``-only features — and any call traced
+  under a multi-device mesh, where GSPMD partitions the program and
+  cannot partition a Mosaic kernel) route to ``mha`` —
   that routing is *policy*, decided here per call signature, unlike the
   silent shape-dependent fallback the flash kernel used to hide inside
   its entry point.
@@ -42,10 +44,10 @@ __all__ = ["BACKENDS", "resolve_backend", "full_attention",
            "decode_attention"]
 
 
-def _tp() -> int:
+def _partitioned() -> bool:
     from ..dist import ctx
 
-    return ctx.axis_size("model")
+    return ctx.partitioned()
 
 
 def resolve_backend(backend: str, *, decode: bool, window=None) -> str:
@@ -54,12 +56,13 @@ def resolve_backend(backend: str, *, decode: bool, window=None) -> str:
     ``window`` is the *positional* sliding-window constraint of the
     call (full-sequence attention only — decode masks by validity, so
     ring-cache decode has no positional window). Kernel-inexpressible
-    signatures (window set, TP sharding active) resolve to ``jnp``.
+    signatures (window set, or a multi-device mesh active — TP sharding
+    included) resolve to ``jnp``.
     """
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown attn backend {backend!r}; known: {BACKENDS}")
-    if window is not None or _tp() > 1:
+    if window is not None or _partitioned():
         return "jnp"
     if backend == "auto":
         if decode:

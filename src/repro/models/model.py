@@ -87,14 +87,24 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_len: int, window="cfg"):
 
 
 def decode_step(params, cfg: ArchConfig, caches, token, window="cfg"):
+    """-> (logits [B, V] in the model's dtype, caches).
+
+    The logits leave behind an optimization barrier: XLA may otherwise
+    fuse a consumer into the unembedding and keep the dot's excess f32
+    precision there, so argmax, a robust aggregate or an attack would
+    each read differently rounded values, and the served tokens would
+    depend on what consumes the logits (seen on TPU v5e, not on CPU).
+    """
     if cfg.family in _TRANSFORMER_FAMILIES:
-        return transformer.decode_step(params, cfg, caches, token,
-                                       window=window)
-    if cfg.family == "hybrid":
-        return hybrid.decode_step(params, cfg, caches, token)
-    if cfg.family == "encdec":
-        return whisper.decode_step(params, cfg, caches, token)
-    raise ValueError(cfg.family)
+        logits, caches = transformer.decode_step(params, cfg, caches, token,
+                                                 window=window)
+    elif cfg.family == "hybrid":
+        logits, caches = hybrid.decode_step(params, cfg, caches, token)
+    elif cfg.family == "encdec":
+        logits, caches = whisper.decode_step(params, cfg, caches, token)
+    else:
+        raise ValueError(cfg.family)
+    return jax.lax.optimization_barrier(logits), caches
 
 
 def param_count(params) -> int:

@@ -30,6 +30,12 @@ from .. import optim as O
 @dataclasses.dataclass(frozen=True)
 class TrainSetup:
     step_fn: Callable
+    # Stacked modes, split at the wire (e.g. to feed one gradient stack
+    # to two aggregation modes): (params, batch, key) -> (loss, per-worker
+    # grads [W, ...] after the attack), and (grads) -> the mode's aggregate
+    # (rrs, auto and mean modes).
+    worker_grads_fn: Callable
+    aggregate_fn: Callable
     params_specs: object
     opt_specs: object
     batch_axes: tuple
@@ -184,7 +190,54 @@ def make_train_step(
 
     _micro_for_static = [1]
 
-    def train_step(params, opt_state, batch, key, agg_state=None):
+    def stacked_specs():
+        return S.stacked_grad_specs(params_specs, worker_axes, mesh,
+                                    shapes=params_shapes)
+
+    def worker_grads(params, batch, key):
+        """Stacked modes: -> (mean loss, per-worker grads [W, ...] after
+        the attack, consensus key or None)."""
+        # split the global batch into per-worker microbatches
+        def split(x):
+            b = x.shape[0]
+            return x.reshape((n_workers, b // n_workers) + x.shape[1:])
+
+        batch_w = jax.tree.map(split, batch)
+        _micro_for_static[0] = _micro_for(batch_w)
+        # spmd_axis_name pins every batched intermediate's worker
+        # dim to the data axes — without it XLA materializes
+        # worker-replicated activations in the backward pass.
+        losses, grads = jax.vmap(
+            worker_grad, in_axes=(None, 0),
+            spmd_axis_name=worker_axes,
+        )(params, batch_w)
+        grads = jax.lax.with_sharding_constraint(
+            grads, S.to_named(mesh, stacked_specs()))
+        k_cons = None
+        if mode == "stacked-consensus":
+            key, k_cons = jax.random.split(key)
+        if n_byz:
+            grads = jax.tree.map(lambda g: attack_fn(key, g, mask), grads)
+        return jnp.mean(losses), grads, k_cons
+
+    def aggregate_stack(grads, k_cons=None, agg_state=None):
+        """The mode's robust aggregation of a per-worker grad stack."""
+        if mode == "stacked-consensus":
+            return RR.aggregate(grads, mesh, worker_axes, mode=mode,
+                                est=est, specs=stacked_specs(),
+                                with_diag=with_diag, consensus=consensus,
+                                plan=fault_plan, key=k_cons,
+                                pin_mask=mask if n_byz else None)
+        if mode == "stacked-adaptive":
+            return RR.aggregate_stacked_adaptive(
+                grads, agg_state, est, with_diag=with_diag,
+                weights_beta=weights_beta, momentum=momentum)
+        return RR.aggregate(grads, mesh, worker_axes, mode=mode, est=est,
+                            specs=stacked_specs(), with_diag=with_diag)
+
+    def robust_grad(params, batch, key, agg_state=None):
+      """-> (loss, aggregate, new_state, caux, diag): everything up to
+      the optimizer update."""
       with CTX.mesh_context(mesh):
           if mode == "inloop":
               # IB-RRS: global backward; heavy matmul grads are robust-
@@ -242,45 +295,8 @@ def make_train_step(
                       loss, grads = jax.value_and_grad(loss_fn)(params, batch)
               agg = grads
           else:
-              # split the global batch into per-worker microbatches
-              def split(x):
-                  b = x.shape[0]
-                  return x.reshape((n_workers, b // n_workers) + x.shape[1:])
-
-              batch_w = jax.tree.map(split, batch)
-              _micro_for_static[0] = _micro_for(batch_w)
-              # spmd_axis_name pins every batched intermediate's worker
-              # dim to the data axes — without it XLA materializes
-              # worker-replicated activations in the backward pass.
-              losses, grads = jax.vmap(
-                  worker_grad, in_axes=(None, 0),
-                  spmd_axis_name=worker_axes,
-              )(params, batch_w)
-              loss = jnp.mean(losses)
-              stacked_specs = S.stacked_grad_specs(
-                  params_specs, worker_axes, mesh, shapes=params_shapes)
-              grads = jax.lax.with_sharding_constraint(
-                  grads, S.to_named(mesh, stacked_specs))
-              if mode == "stacked-consensus":
-                  key, k_cons = jax.random.split(key)
-              if n_byz:
-                  grads = jax.tree.map(
-                      lambda g: attack_fn(key, g, mask), grads)
-              if mode == "stacked-consensus":
-                  agg = RR.aggregate(grads, mesh, worker_axes, mode=mode,
-                                     est=est, specs=stacked_specs,
-                                     with_diag=with_diag,
-                                     consensus=consensus, plan=fault_plan,
-                                     key=k_cons,
-                                     pin_mask=mask if n_byz else None)
-              elif mode == "stacked-adaptive":
-                  agg = RR.aggregate_stacked_adaptive(
-                      grads, agg_state, est, with_diag=with_diag,
-                      weights_beta=weights_beta, momentum=momentum)
-              else:
-                  agg = RR.aggregate(grads, mesh, worker_axes, mode=mode,
-                                     est=est, specs=stacked_specs,
-                                     with_diag=with_diag)
+              loss, grads, k_cons = worker_grads(params, batch, key)
+              agg = aggregate_stack(grads, k_cons, agg_state)
           diag = caux = new_state = None
           if mode == "stacked-consensus":
               if with_diag:
@@ -296,6 +312,21 @@ def make_train_step(
               agg, diag = agg
           agg = jax.lax.with_sharding_constraint(
               agg, S.to_named(mesh, params_specs))
+          return loss, agg, new_state, caux, diag
+
+    def worker_grads_fn(params, batch, key):
+        with CTX.mesh_context(mesh):
+            loss, grads, _ = worker_grads(params, batch, key)
+        return loss, grads
+
+    def aggregate_fn(grads):
+        with CTX.mesh_context(mesh):
+            return aggregate_stack(grads)
+
+    def train_step(params, opt_state, batch, key, agg_state=None):
+      loss, agg, new_state, caux, diag = robust_grad(params, batch, key,
+                                                     agg_state)
+      with CTX.mesh_context(mesh):
           new_params, new_opt = optimizer.update(agg, opt_state, params)
           new_params = jax.lax.with_sharding_constraint(
               new_params, S.to_named(mesh, params_specs))
@@ -310,6 +341,8 @@ def make_train_step(
 
     return TrainSetup(
         step_fn=train_step,
+        worker_grads_fn=worker_grads_fn,
+        aggregate_fn=aggregate_fn,
         params_specs=params_specs,
         opt_specs=opt_specs,
         batch_axes=batch_axes,

@@ -234,6 +234,32 @@ def test_decode_kernel_batch_tiling(blk_b):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_decode_kernel_narrow_layout(kv):
+    """The compiled (narrow) layout's math, run in interpret mode:
+    scalar-prefetched per-row lengths and, for int8, dequant scales on
+    score and probability columns instead of on the K/V block."""
+    from repro.kernels.decode_attention import _decode_grouped
+
+    B, H, Hkv, dh, T = 3, 8, 2, 32, 40
+    q, k, v = _qkv(jax.random.PRNGKey(6), B, H, Hkv, dh, T)
+    lens = jnp.asarray([7, 40, 23], jnp.int32)
+    ks = vs = None
+    if kv == "int8":
+        k, ks = quantize_kv(k, jnp.int8)
+        v, vs = quantize_kv(v, jnp.int8)
+        kd = k.astype(jnp.float32) * ks[:, :, None, None]
+        vd = v.astype(jnp.float32) * vs[:, :, None, None]
+    else:
+        kd, vd = k, v
+    want = mha(q, kd, vd, causal=False, window=None, chunk=1, kv_len=lens)
+    got = _decode_grouped(q[:, 0].reshape(B, Hkv, H // Hkv, dh), k, v, lens,
+                          ks, vs, blk_k=16, blk_b=B, interpret=True,
+                          narrow=True)
+    np.testing.assert_allclose(np.asarray(got.reshape(want.shape)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
 def test_decode_kernel_scale_validation():
     q, k, v = _qkv(jax.random.PRNGKey(5), 2, 4, 2, 32, 16)
     ks = jnp.ones((2, 16), jnp.float32)

@@ -208,7 +208,8 @@ from repro.dist import robust_reduce as RR
 from repro.dist.consensus import (ConsensusConfig, aggregate_stacked_consensus,
                                   consensus_aggregate)
 from repro.dist.faults import FaultPlan
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 g = {"w": jax.random.normal(jax.random.PRNGKey(2), (8, 12, 8)),
      "b": jax.random.normal(jax.random.PRNGKey(3), (8, 7))}
 sh = {"w": NamedSharding(mesh, P("data", None, "model")),
@@ -257,7 +258,8 @@ from repro.dist import sharding as S
 from repro.dist.consensus import ConsensusConfig
 from repro.dist.faults import FaultPlan
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_arch("qwen3-1.7b").reduced()
 plan = FaultPlan(dropout=0.1, n_crashed=1, crash_round=2)
 setup = make_train_step(cfg, mesh, estimator="vrmom",

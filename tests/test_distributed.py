@@ -30,7 +30,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import robust_reduce as RR
 from repro.kernels import ref as kref
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 key = jax.random.PRNGKey(0)
 grads = {
   "a": {"w_gate": jax.random.normal(key, (4, 6, 16))},   # model-sharded dim 2
@@ -63,7 +64,8 @@ from repro.train.step import make_train_step
 import repro.optim as O
 from repro.dist import sharding as S
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_arch("qwen3-1.7b").reduced()
 params = M.init(jax.random.PRNGKey(0), cfg)
 
@@ -106,7 +108,8 @@ def test_stacked_auto_equals_rrs():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import robust_reduce as RR
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 g = {"w_up": jax.random.normal(jax.random.PRNGKey(2), (8, 12, 8))}
 sh = {"w_up": NamedSharding(mesh, P("data", None, "model"))}
 gp = jax.tree.map(jax.device_put, g, sh)
@@ -125,7 +128,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import robust_reduce as RR
 from repro.kernels import ref as kref
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 W = 4
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (8, 6, 10))          # batch 8 = 4 workers x 2
@@ -169,7 +173,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import robust_reduce as RR
 from repro.kernels import ref as kref
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 W = 4
 g = {"w_up": jax.random.normal(jax.random.PRNGKey(0), (W, 8, 16))}
 sh = {"w_up": NamedSharding(mesh, P(("pod", "data"), None, "model"))}
@@ -194,7 +199,8 @@ from repro.models import model as M
 from repro.train.step import make_train_step
 import repro.optim as O
 from repro.dist import sharding as S
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_arch("mamba2-2.7b").reduced()
 setup = make_train_step(cfg, mesh, byzantine_frac=0.3, attack="gaussian",
                         lr=1e-2, microbatch=1)

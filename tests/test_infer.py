@@ -9,6 +9,7 @@ import pytest
 from repro.core import attacks, rcsl as R, vrmom as V
 from repro.core.estimator import Estimator
 from repro.dist.robust_reduce import aggregate_symmetric_stacked
+from repro.launch.mesh import make_mesh
 from repro.infer import (bvn_cdf, confidence_intervals,
                          contamination_inflation, corrupt_stats, cov_factor,
                          coverage_run, infer, machine_stats, mom_cov_factor,
@@ -295,6 +296,6 @@ def test_coverage_rejects_indivisible_mesh_reps():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >1 device")
-    mesh = jax.make_mesh((len(devs),), ("data",))
+    mesh = make_mesh((len(devs),), ("data",))
     with pytest.raises(ValueError, match="not divisible"):
         coverage_run(reps=len(devs) + 1, mesh=mesh)

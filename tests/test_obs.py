@@ -507,7 +507,8 @@ from repro.train.step import make_train_step
 import repro.optim as O
 from repro.dist import sharding as S
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_arch("qwen3-1.7b").reduced()
 params = M.init(jax.random.PRNGKey(0), cfg)
 
@@ -561,7 +562,8 @@ def test_rrs_aggregate_with_diag_matches_plain():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import robust_reduce as RR
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 g = {"w": jax.random.normal(jax.random.PRNGKey(0), (4, 6, 16)) + 2.0}
 g["w"] = g["w"].at[3].multiply(-1.0)  # worker 3 signflips on the wire
 sh = {"w": NamedSharding(mesh, P("data", None, "model"))}

@@ -316,7 +316,8 @@ from repro.models import model as M
 from repro.train.step import make_train_step
 import repro.optim as O
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_arch("qwen3-1.7b").reduced()
 setup = make_train_step(cfg, mesh, estimator="auto_gm",
                         byzantine_frac=0.15, attack="ipm", lr=1e-2,

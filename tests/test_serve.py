@@ -332,7 +332,8 @@ want = np.stack([np.asarray(
     eng.generate({"tokens": batch["tokens"][i:i+1]}, 6))[0]
     for i in range(4)])
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 pool = eng.make_pool()
 specs = C.pool_specs(cfg, pool, mesh, batch_axes=("data",))
 named = S.to_named(mesh, specs)
