@@ -1,0 +1,7 @@
+"""Global tokens of every step completed in the window over the window
+(host clock)."""
+
+
+def read(ctx):
+    rec = ctx["rec"]
+    return rec["tokens"] / rec["elapsed_s"]
