@@ -16,10 +16,11 @@ Two layouts of the same online-softmax math (DESIGN.md §8):
   innermost (sequential, accumulating into scratch; the output block is
   written on the last kv step). Blocks are 2-D MXU-shaped: q ``[G,
   dh]``, K/V ``[blk_k, dh]``. K/V are viewed as ``[B, T, Hkv*dh]`` — a
-  free reshape of the serving cache layout ``[B, T, Hkv, dh]`` — so the
-  per-kv-head slab is a plain block of the last two dims (lane-aligned
-  for dh in {64, 128}) with no transpose of the cache. Per-row lengths
-  are scalar-prefetched into SMEM.
+  reshape of the serving cache layout ``[B, T, Hkv, dh]``, which on a
+  TPU copies the cache into the new tiled layout (scope
+  ``decode.kv_cache``) — so the per-kv-head slab is a plain block of the
+  last two dims (lane-aligned for dh in {64, 128}) with no transpose of
+  the cache. Per-row lengths are scalar-prefetched into SMEM.
 * **wide** (interpret mode, host CPU): grid ``(n_batch_blocks,
   n_kv_blocks)`` — kv innermost — with a ``[blk_b, Hkv, G, dh]`` query
   block and ``[blk_b, blk_k, Hkv*dh]`` K/V blocks resident at once,
@@ -54,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..obs.trace import named_span
 
 NEG_INF = -1e30
 
@@ -257,19 +260,21 @@ def _decode_grouped(q, k, v, lens, k_scale, v_scale, blk_k, blk_b,
     T = k.shape[1]
     blk_k = min(blk_k, T)
     pad_k = (-T) % blk_k
-    if pad_k:
-        # padded slots fall beyond kv_len <= T: masked out in-kernel
-        padw = ((0, 0), (0, pad_k), (0, 0), (0, 0))
-        k = jnp.pad(k, padw)
-        v = jnp.pad(v, padw)
-        if k_scale is not None:
-            k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k)))
-            v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k)))
     Tk = T + pad_k
-    # Free reshape: the per-kv-head [blk_k, dh] slab becomes a plain
-    # block of the last two dims — the cache is never transposed.
-    k2 = k.reshape(B, Tk, Hkv * dh)
-    v2 = v.reshape(B, Tk, Hkv * dh)
+    with named_span("decode.kv_cache"):
+        if pad_k:
+            # padded slots fall beyond kv_len <= T: masked out in-kernel
+            padw = ((0, 0), (0, pad_k), (0, 0), (0, 0))
+            k = jnp.pad(k, padw)
+            v = jnp.pad(v, padw)
+            if k_scale is not None:
+                k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k)))
+                v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k)))
+        # The per-kv-head [blk_k, dh] slab becomes a plain block of the
+        # last two dims; the cache is never transposed, but on a TPU the
+        # reshape changes the tiled layout and copies.
+        k2 = k.reshape(B, Tk, Hkv * dh)
+        v2 = v.reshape(B, Tk, Hkv * dh)
     kw = dict(scale=1.0 / (dh ** 0.5), blk_k=blk_k, n_k=Tk // blk_k,
               interpret=interpret)
     if narrow if narrow is not None else not interpret:
@@ -331,8 +336,6 @@ def decode_attention(q, k, v, *, kv_len=None, blk_k=None, blk_b=None,
             jnp.asarray(k_scale, jnp.float32), (B, T))
         v_scale = jnp.broadcast_to(
             jnp.asarray(v_scale, jnp.float32), (B, T))
-    from ..obs.trace import named_span
-
     with named_span("kernels.decode_attention"):
         out = _decode_grouped(qg, k, v, lens, k_scale, v_scale,
                               int(blk_k), int(blk_b or B),

@@ -23,6 +23,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import named_span
 from .layers import dense_init, rmsnorm, rope
 
 NEG_INF = -1e30
@@ -274,27 +275,28 @@ def attn_decode(p, x1, cfg, cache: KVCache, *, window="cfg"):
     q, k, v = _qkv(p, x1, cfg, positions)
     T = cache.k.shape[1]
     slot = jnp.mod(pos, T) if window else jnp.minimum(pos, T - 1)
-    # quantize the fresh K/V row once, at write time (no-op cast when the
-    # cache dtype matches compute_dtype)
-    k, ks1 = quantize_kv(k, cache.k.dtype)
-    v, vs1 = quantize_kv(v, cache.v.dtype)
     kscale, vscale = cache.k_scale, cache.v_scale
-    if per_row:
-        upd = jax.vmap(
-            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
-        ck = upd(cache.k, k, slot)
-        cv = upd(cache.v, v, slot)
-        if ks1 is not None:
-            upd1 = jax.vmap(
-                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s,)))
-            kscale = upd1(kscale, ks1, slot)
-            vscale = upd1(vscale, vs1, slot)
-    else:
-        ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache.v, v, (0, slot, 0, 0))
-        if ks1 is not None:
-            kscale = jax.lax.dynamic_update_slice(kscale, ks1, (0, slot))
-            vscale = jax.lax.dynamic_update_slice(vscale, vs1, (0, slot))
+    with named_span("decode.kv_cache"):
+        # quantize the fresh K/V row once, at write time (no-op cast when
+        # the cache dtype matches compute_dtype)
+        k, ks1 = quantize_kv(k, cache.k.dtype)
+        v, vs1 = quantize_kv(v, cache.v.dtype)
+        if per_row:
+            upd = jax.vmap(
+                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
+            ck = upd(cache.k, k, slot)
+            cv = upd(cache.v, v, slot)
+            if ks1 is not None:
+                upd1 = jax.vmap(
+                    lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s,)))
+                kscale = upd1(kscale, ks1, slot)
+                vscale = upd1(vscale, vs1, slot)
+        else:
+            ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cache.v, v, (0, slot, 0, 0))
+            if ks1 is not None:
+                kscale = jax.lax.dynamic_update_slice(kscale, ks1, (0, slot))
+                vscale = jax.lax.dynamic_update_slice(vscale, vs1, (0, slot))
     # Ring buffer (window set): all T slots valid once pos >= T; slot
     # positions don't matter for masking beyond validity (window == ring
     # size). Linear cache: the first pos+1 slots are valid.

@@ -1,35 +1,52 @@
-"""Profiler spans: name the subsystems in ``jax.profiler`` traces.
+"""Profiler spans: name the program's work in ``jax.profiler`` traces.
 
 Two helpers for the two sides of the jit boundary:
 
-* ``trace_span(name)`` — host-side wall-clock span
-  (``jax.profiler.TraceAnnotation``): wraps dispatch + blocking work so
-  the profiler timeline attributes host time per subsystem.
-* ``named_span(name)`` — in-trace annotation (``jax.named_scope``):
-  names the ops staged out while it is active, so the compiled HLO (and
-  the device-side profile) carries the subsystem name. Zero runtime
-  cost — it only decorates metadata at trace time.
+* ``trace_span(name, **args)`` — host span
+  (``jax.profiler.TraceAnnotation``) on the calling thread's line of
+  the trace. Keyword arguments become the event's stats (``uid``,
+  ``slot``, ...). Spans nest by containment on one thread. With no
+  profiler active a span costs one flag check, so the serving path
+  keeps them on.
+* ``named_span(name)`` — ``jax.named_scope``: a component of the name
+  stack of every op staged under it. It reaches the compiled HLO as
+  ``metadata={op_name=".../<name>/..."}``, and the TPU profiler shows
+  that path beside each device op. Backward ops keep the forward's
+  path inside ``transpose(...)``. It exists only at trace time and
+  costs nothing at run time.
 
-The repo's hot paths are pre-annotated with the DESIGN.md §11 span
-names: ``rrs.all_to_all`` (the robust-reduce wire), ``kernels.aggregate``
-(the fused Pallas aggregation family), ``kernels.decode_attention``,
-``serve.decode_scan`` (the engine's fused decode loop), and
-``consensus.round_loop`` (the §13 peer-to-peer round iteration).
+Host spans (``serve/scheduler.py``, ``serve/engine.py``):
+``serve.step`` (args ``active``, ``queued``) holds one scheduler cycle;
+``serve.admit`` (``uid``, ``slot``, ``prompt_len``, ``queue_wait_us``)
+one admission, holding ``serve.prefill`` (``prompt_len``),
+``serve.write_slot`` (``slot``) and ``serve.first_token``;
+``serve.decode_block`` (``n_steps``) the decode loop's dispatch;
+``serve.evict`` (``uid``, ``slot``) a retirement; ``serve.wait``
+(``what``) each point where the host blocks on a device result.
+
+Device scopes: ``train.grad``, ``rrs.aggregate`` (holding
+``rrs.all_to_all``) and ``train.optimizer`` in the train step;
+``decode.kv_cache`` around the per-row cache write and the cache's
+relayout ahead of the decode-attention kernel;
+``kernels.aggregate``, ``kernels.aggregate_sample``,
+``kernels.decode_attention``, ``serve.decode_scan`` and
+``consensus.round_loop``.
+
+Each jitted serve program is named by its function, so the profiler's
+module line reads ``jit_serve_prefill``, ``jit_serve_first_token``,
+``jit_serve_decode_block``, ``jit_serve_stack_flatten`` and
+``jit_serve_decode_step``; the train step is ``jit_train_step``.
 """
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
 __all__ = ["trace_span", "named_span"]
 
 
-@contextlib.contextmanager
-def trace_span(name: str):
-    """Host-side profiler span (shows up in jax.profiler traces)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def trace_span(name: str, **args):
+    """Host profiler span; ``args`` show as the event's stats."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def named_span(name: str):

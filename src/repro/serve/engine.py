@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import model as M
+from ..obs.trace import named_span, trace_span
 from . import cache as C
 from . import robust as R
 
@@ -163,13 +164,13 @@ class ServeEngine:
         return fn
 
     def _prefill_fn(self):
-        def run(params, batch):
+        def serve_prefill(params, batch):
             logits, caches = M.prefill(params, self.cfg, batch,
                                        window=self.window,
                                        cache_len=self.max_len, last_only=True)
             return logits[:, -1], caches
 
-        return self._fn("prefill", lambda: jax.jit(run))
+        return self._fn("prefill", lambda: jax.jit(serve_prefill))
 
     def _prefill_dims(self, batch):
         """Per-leaf batch-dim indices of the prefill cache tree.
@@ -228,7 +229,7 @@ class ServeEngine:
         stochastic = sc.method != "greedy" or (
             rcfg is not None and rcfg.attack != "none")
 
-        def run(params, caches, tok, key, active=None):
+        def serve_decode_block(params, caches, tok, key, active=None):
             # active: optional [B] bool — pool-path slot liveness. Only
             # the diag aux reads it (inactive slots decode stale caches;
             # their disagreement rates are masked out of the histogram);
@@ -273,8 +274,6 @@ class ServeEngine:
                     nxt = sample_tokens(logits, skey, sc)
                 return (nxt, caches, key), (nxt, dis) if diag else nxt
 
-            from ..obs.trace import named_span
-
             with named_span("serve.decode_scan"):
                 (tok, caches, _), ys = jax.lax.scan(
                     body, (tok, caches, key), None, length=n_steps)
@@ -297,14 +296,15 @@ class ServeEngine:
         # re-feed the same caches across calls, which donation forbids.
         return self._fn(("loop", n_steps, sc, pool, diag, donate),
                         lambda: jax.jit(
-                            run, donate_argnums=(1,) if donate else ()))
+                            serve_decode_block,
+                            donate_argnums=(1,) if donate else ()))
 
     def _decode_step_fn(self, sc: Sampling):
         """Single-step dispatch — the Python-loop baseline the scan
         replaces (kept for benchmarks and debugging)."""
         rcfg = self.robust
 
-        def run(params, caches, tok, key):
+        def serve_decode_step(params, caches, tok, key):
             akey, skey = jax.random.split(key)
             if rcfg is not None:
                 logits, caches = R.robust_decode_step(
@@ -315,7 +315,7 @@ class ServeEngine:
                                                window=self.window)
             return sample_tokens(logits, skey, sc), caches
 
-        return self._fn(("step", sc), lambda: jax.jit(run))
+        return self._fn(("step", sc), lambda: jax.jit(serve_decode_step))
 
     def _drain_serve_diag(self, sd, n: int) -> None:
         """Fold a jit-side ``ServeDiag`` aux into the host registry:
@@ -352,7 +352,7 @@ class ServeEngine:
         """
         rcfg = self.robust
 
-        def run(logits, key):
+        def serve_first_token(logits, key):
             if rcfg is not None:
                 rep = jnp.broadcast_to(logits[None],
                                        (rcfg.m,) + logits.shape)
@@ -360,7 +360,8 @@ class ServeEngine:
                                        jax.random.fold_in(key, 0), sc)
             return sample_tokens(logits, jax.random.fold_in(key, 0), sc)
 
-        return self._fn(("first", sc), lambda: jax.jit(run))(logits, key)
+        return self._fn(("first", sc),
+                        lambda: jax.jit(serve_first_token))(logits, key)
 
     def _stack_flatten_fn(self, batch):
         """Jitted prefill-cache -> replica-flat conversion (cached per
@@ -369,11 +370,11 @@ class ServeEngine:
         leaves, treedef = jax.tree.flatten(dims)
         m = self.robust.m
 
-        def run(caches):
+        def serve_stack_flatten(caches):
             return R.flatten_replicas(R.stack_replicas(caches, m), dims, m)
 
         return self._fn(("stack-flatten", tuple(leaves), treedef),
-                        lambda: jax.jit(run))
+                        lambda: jax.jit(serve_stack_flatten))
 
     def generate(self, batch, n_tokens: int, sampling: Sampling = GREEDY,
                  key=None):
@@ -430,13 +431,17 @@ class ServeEngine:
             raise ValueError(f"prompt ({prompt_len}) must leave decode room "
                              f"in max_len ({self.max_len})")
         key = jax.random.PRNGKey(int(slot)) if key is None else key
-        logits, caches = self.prefill(batch)
+        with trace_span("serve.prefill", prompt_len=prompt_len):
+            logits, caches = self.prefill(batch)
         caches = C.vectorize_pos(caches, 1)
         if self._replicated:
             caches = R.stack_replicas(caches, self.robust.m)
-        pool = C.write_slot(pool, self._dims, caches, slot, prompt_len)
-        tok = self._first_token(logits, key, sampling)
-        return pool, int(tok[0])
+        with trace_span("serve.write_slot", slot=slot):
+            pool = C.write_slot(pool, self._dims, caches, slot, prompt_len)
+        with trace_span("serve.first_token"):
+            tok = self._first_token(logits, key, sampling)
+        with trace_span("serve.wait", what="first_token"):
+            return pool, int(tok[0])
 
     def decode_pool(self, pool: C.SlotPool, cur_tok, n_steps: int,
                     sampling: Sampling = GREEDY, key=None):
@@ -451,19 +456,18 @@ class ServeEngine:
         # rows); the jitted loop runs the block replica-flat and
         # restores the layout before returning.
         fn = self._decode_loop_fn(n_steps, sampling, pool=True)
+        # the diag aux masks inactive slots (stale caches decode garbage
+        # — their disagreement rates would dilute the live Byzantine
+        # signal), so drain with the live sample count.
         diag = self.obs is not None and self.robust is not None
-        if diag:
-            # the diag aux masks inactive slots (stale caches decode
-            # garbage — their disagreement rates would dilute the live
-            # Byzantine signal), so drain with the live sample count.
-            out = fn(self.params, pool.caches,
-                     jnp.asarray(cur_tok, jnp.int32), key, pool.active)
-        else:
-            out = fn(self.params, pool.caches,
-                     jnp.asarray(cur_tok, jnp.int32), key)
+        args = (self.params, pool.caches, jnp.asarray(cur_tok, jnp.int32),
+                key) + ((pool.active,) if diag else ())
+        with trace_span("serve.decode_block", n_steps=n_steps):
+            out = fn(*args)
         toks, caches = out[0], out[1]
         if len(out) == 3:
-            n_active = int(jax.device_get(pool.active).sum())
+            with trace_span("serve.wait", what="active"):
+                n_active = int(jax.device_get(pool.active).sum())
             self._drain_serve_diag(out[2], n_steps * n_active)
         lengths = jnp.where(pool.active, pool.lengths + n_steps, pool.lengths)
         return C.SlotPool(caches, lengths, pool.active), toks

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from ..obs.metrics import now as _now
+from ..obs.trace import trace_span
 from .engine import GREEDY, Sampling, ServeEngine
 
 __all__ = ["Request", "Completion", "Scheduler"]
@@ -127,7 +128,8 @@ class Scheduler:
             self._obs.counter("serve.tokens_out", len(self._slot_out[slot]))
         self._slot_req[slot] = None
         self._slot_out[slot] = []
-        self.pool = self.engine.evict(self.pool, slot)
+        with trace_span("serve.evict", uid=req.uid, slot=slot):
+            self.pool = self.engine.evict(self.pool, slot)
 
     def _ingest(self, slot: int, new_tokens: List[int]) -> None:
         """Append a slot's new tokens, trimming at EOS / budget, and
@@ -173,9 +175,12 @@ class Scheduler:
             shape_key = (req.tokens.shape[0],
                          tuple(sorted(req.extras)) if req.extras else ())
             t_admit = _now()
-            self.pool, first = self.engine.admit(
-                self.pool, slot, batch, sampling=self.sampling,
-                key=self._next_key())
+            with trace_span("serve.admit", uid=req.uid, slot=slot,
+                            prompt_len=req.tokens.shape[0],
+                            queue_wait_us=1e6 * (t_admit - req.submit_t)):
+                self.pool, first = self.engine.admit(
+                    self.pool, slot, batch, sampling=self.sampling,
+                    key=self._next_key())
             if self._obs is not None:
                 self._obs.counter("serve.admitted")
                 if shape_key not in self._warm_prefill:
@@ -200,6 +205,11 @@ class Scheduler:
 
     def step(self) -> bool:
         """One admit + decode-block cycle. Returns False when idle."""
+        with trace_span("serve.step", active=len(self._active_slots()),
+                        queued=len(self.queue)):
+            return self._step()
+
+    def _step(self) -> bool:
         self._admit()
         active = self._active_slots()
         if not active:
@@ -211,8 +221,9 @@ class Scheduler:
         self.pool, toks = self.engine.decode_pool(
             self.pool, self._cur_tok, self.decode_block,
             sampling=self.sampling, key=self._next_key())
-        toks = np.asarray(toks)  # [decode_block, n_slots] (blocks: device
-        #                          work done — the block time is real)
+        with trace_span("serve.wait", what="decode_block"):
+            # [decode_block, n_slots]; blocks: the block time is real
+            toks = np.asarray(toks)
         if self._obs is not None:
             if self._decode_warm:
                 self._obs.observe("serve.decode_step_s",

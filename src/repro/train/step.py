@@ -24,6 +24,7 @@ from ..dist import ctx as CTX
 from ..dist import robust_reduce as RR
 from ..dist import sharding as S
 from ..models import model as M
+from ..obs.trace import named_span
 from .. import optim as O
 
 
@@ -295,8 +296,10 @@ def make_train_step(
                       loss, grads = jax.value_and_grad(loss_fn)(params, batch)
               agg = grads
           else:
-              loss, grads, k_cons = worker_grads(params, batch, key)
-              agg = aggregate_stack(grads, k_cons, agg_state)
+              with named_span("train.grad"):
+                  loss, grads, k_cons = worker_grads(params, batch, key)
+              with named_span("rrs.aggregate"):
+                  agg = aggregate_stack(grads, k_cons, agg_state)
           diag = caux = new_state = None
           if mode == "stacked-consensus":
               if with_diag:
@@ -327,7 +330,8 @@ def make_train_step(
       loss, agg, new_state, caux, diag = robust_grad(params, batch, key,
                                                      agg_state)
       with CTX.mesh_context(mesh):
-          new_params, new_opt = optimizer.update(agg, opt_state, params)
+          with named_span("train.optimizer"):
+              new_params, new_opt = optimizer.update(agg, opt_state, params)
           new_params = jax.lax.with_sharding_constraint(
               new_params, S.to_named(mesh, params_specs))
           out = (new_params, new_opt, loss)
