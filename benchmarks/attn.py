@@ -7,7 +7,9 @@ whatever backend this host has (the Pallas kernel runs in interpret
 mode off-TPU: wide-tile config, correctness- and trend-representative).
 The jnp row is the chunked ``mha`` exactly as the models run it
 (per-row ``kv_len``, f32 scores); the flash row is
-``kernels/decode_attention`` through the same jit.
+``kernels/decode_attention`` through the same jit, reading the cache as
+a one-layer pool ``[1, B, T, Hkv*dh]`` (layer 0), the layout the
+serving decode loop hands it.
 
 Emits ``BENCH_attn.json``:
 
@@ -55,7 +57,8 @@ def bench_decode(B=8, H=32, Hkv=8, dh=128, seqs=(256, 1024, 4096), iters=5,
     rows, us_table = [], {}
     f_jnp = jax.jit(lambda q, k, v, l: mha(q, k, v, causal=False, window=None,
                                            chunk=1, kv_len=l))
-    f_flash = jax.jit(lambda q, k, v, l: decode_attention(q, k, v, kv_len=l))
+    f_flash = jax.jit(lambda q, k, v, l: decode_attention(q, k, v, 0,
+                                                           kv_len=l))
     for T in seqs:
         ks = jax.random.split(jax.random.PRNGKey(T), 3)
         q = jax.random.normal(ks[0], (B, 1, H, dh))
@@ -64,10 +67,11 @@ def bench_decode(B=8, H=32, Hkv=8, dh=128, seqs=(256, 1024, 4096), iters=5,
         # per-row lengths: the slot-serving signature (rows at different
         # fill levels), not the easier scalar special case
         lens = jnp.linspace(T // 2, T, B).astype(jnp.int32)
+        kp, vp = (x.reshape(1, B, T, Hkv * dh) for x in (k, v))
         err = float(jnp.max(jnp.abs(f_jnp(q, k, v, lens)
-                                    - f_flash(q, k, v, lens))))
+                                    - f_flash(q, kp, vp, lens))))
         us = {"jnp": _time(f_jnp, q, k, v, lens, iters=iters),
-              "flash": _time(f_flash, q, k, v, lens, iters=iters)}
+              "flash": _time(f_flash, q, kp, vp, lens, iters=iters)}
         us_table[f"T{T}"] = us
         for backend, t in us.items():
             rows.append((f"attn/decode/{backend}/b{B}xT{T}", t,

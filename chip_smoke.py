@@ -242,9 +242,16 @@ def kernel_phase(*, agg_shape=AGG_SHAPE, decode_shape=DECODE_SHAPE,
     v8, s_v = quantize_kv(vf, jnp.int8)
     caches["int8"] = (k8, v8, s_k, s_v)
     for name, (k, v, sk, sv) in caches.items():
+        # the cache as the second layer of a two-layer pool
+        # [2, B, T, Hkv*dh] (scales [2, B, T]), read by layer index
+        k, v = (jnp.stack([x[::-1], x]).reshape(2, B, T, Hkv * dh)
+                for x in (k, v))
+        if sk is not None:
+            sk, sv = jnp.stack([sk[::-1], sk]), jnp.stack([sv[::-1], sv])
+
         def run(c, q, k, v, lens, sk, sv):
-            return AB.decode_attention(q, k, v, c, kv_len=lens, k_scale=sk,
-                                       v_scale=sv)
+            return AB.decode_attention(q, k, v, 1, c, kv_len=lens,
+                                       k_scale=sk, v_scale=sv)
 
         got = jax.jit(functools.partial(run, flash))(q, k, v, lens, sk, sv)
         with jax.default_matmul_precision("highest"):

@@ -175,8 +175,10 @@ def cache_specs(cfg, cache_shapes, mesh, batch_axes, global_batch=None):
         b_dim = None
         if global_batch is not None and batch_axes is not None and nd >= 2:
             # Batch sits after the layer-stack dims: dim 1 for plain
-            # stacked caches [L, B, ...], dim 2 for hybrid group stacks
-            # [G, every, B, ...]. Size-matching cannot fully
+            # stacked caches [L, B, ...] (the K/V pool [L, B, T,
+            # Hkv*dh], whose widest tail dim is T or the folded heads),
+            # dim 2 for hybrid group stacks [G, every, B, ...].
+            # Size-matching cannot fully
             # disambiguate (a stack dim may equal the batch size);
             # preference order 1 > 2 > 0 resolves the common layouts,
             # and a wrong pick still yields a valid (divisible) if

@@ -60,7 +60,9 @@ AST_RULES = (
     RuleInfo(
         "RL005", "impure-index-map",
         "Pallas BlockSpec index maps are pure arithmetic functions of "
-        "the grid indices: no calls, attribute reads, or subscripts.",
+        "the grid indices and reads of their own scalar-prefetch "
+        "arguments: no calls, attribute reads, or subscripts of captured "
+        "state.",
         "DESIGN §7-§8 kernel discipline"),
     RuleInfo(
         "RL006", "unmasked-padded-load",

@@ -420,7 +420,9 @@ class IndexMapPurityRule(Rule):
     """An index map runs at grid-scheduling time: anything beyond
     arithmetic on the grid indices (calls, attribute reads, subscripts
     into captured state) is either miscompiled or a hidden host
-    dependency. Pure = names, constants, arithmetic, tuples."""
+    dependency. Pure = names, constants, arithmetic, tuples, and reads
+    of the map's own scalar-prefetch arguments (``layer[0]`` where
+    ``layer`` is a parameter: the SMEM operand Pallas hands the map)."""
 
     id = "RL005"
 
@@ -449,7 +451,12 @@ class IndexMapPurityRule(Rule):
             imap = self._index_map(node)
             if not isinstance(imap, ast.Lambda):
                 continue
+            params = {a.arg for a in imap.args.args}
             for sub in ast.walk(imap.body):
+                if (isinstance(sub, ast.Subscript)
+                        and isinstance(sub.value, ast.Name)
+                        and sub.value.id in params):
+                    continue  # scalar-prefetch read; its index is walked
                 if isinstance(sub, self._IMPURE):
                     yield self.finding(
                         relpath, imap.lineno,

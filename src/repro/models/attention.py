@@ -36,7 +36,11 @@ KV_DTYPES = ("float32", "bfloat16", "int8")
 
 
 class KVCache(NamedTuple):
-    k: jnp.ndarray  # [B, T, Hkv, dh] (T = max_len or window size)
+    # [B, T, Hkv*dh] (T = max_len or window size): a kv head's [T, dh]
+    # slab is a plain block of the last two dims, so the decode kernel
+    # reads the layer-stacked pool [L, B, T, Hkv*dh] as it rests
+    # (DESIGN.md §6/§8)
+    k: jnp.ndarray
     v: jnp.ndarray
     pos: jnp.ndarray  # [] int32 — number of tokens already written
     # int8 KV only: per-(row, position) f32 dequant scales [B, T],
@@ -213,31 +217,33 @@ def attn_forward(p, x, cfg, *, positions, causal=True, window="cfg",
     out = _out_proj(out, p["wo"])
     cache = None
     if make_cache:
-        S = k.shape[1]
+        B, S = k.shape[:2]
+        # quantize and fold the heads into the cache layout before the
+        # padding to cache length, so only the prompt's rows relayout
+        dt = kv_dtype(cfg)
+        k, ks = quantize_kv(k, dt)
+        v, vs = quantize_kv(v, dt)
+        k = k.reshape(B, S, -1)
+        v = v.reshape(B, S, -1)
         if window:
             # Ring cache of exactly `window` slots; position p lives at
             # slot p % window so decode can keep writing in ring order.
             w = window
             if S >= w:
-                ck = jnp.roll(k[:, -w:], S % w, axis=1)
-                cv = jnp.roll(v[:, -w:], S % w, axis=1)
+                fit = lambda x: jnp.roll(x[:, -w:], S % w, axis=1)
             else:
-                padw = ((0, 0), (0, w - S), (0, 0), (0, 0))
-                ck, cv = jnp.pad(k, padw), jnp.pad(v, padw)
+                fit = lambda x: jnp.pad(
+                    x, ((0, 0), (0, w - S)) + ((0, 0),) * (x.ndim - 2))
         else:
             T = cache_len or S
-            if T == S:
-                ck, cv = k, v
-            elif T > S:
-                padw = ((0, 0), (0, T - S), (0, 0), (0, 0))
-                ck, cv = jnp.pad(k, padw), jnp.pad(v, padw)
+            if T >= S:
+                fit = lambda x: jnp.pad(
+                    x, ((0, 0), (0, T - S)) + ((0, 0),) * (x.ndim - 2))
             else:
-                ck, cv = k[:, :T], v[:, :T]
-        dt = kv_dtype(cfg)
-        ck, ks = quantize_kv(ck, dt)
-        cv, vs = quantize_kv(cv, dt)
-        cache = KVCache(k=ck, v=cv, pos=jnp.asarray(S, jnp.int32),
-                        k_scale=ks, v_scale=vs)
+                fit = lambda x: x[:, :T]
+        cache = jax.tree.map(fit, KVCache(
+            k=k, v=v, pos=None, k_scale=ks, v_scale=vs))._replace(
+                pos=jnp.asarray(S, jnp.int32))
     return out, cache
 
 
@@ -246,7 +252,7 @@ def init_cache(cfg, batch: int, max_len: int, window: Optional[int] = None,
     """Empty KV cache. With a window, the cache is a ring of that size."""
     T = min(window, max_len) if window else max_len
     dt = kv_dtype(cfg)
-    shape = (batch, T, cfg.n_kv_heads, cfg.head_dim)
+    shape = (batch, T, cfg.n_kv_heads * cfg.head_dim)
     ks = vs = None
     if dt == jnp.int8:
         ks = jnp.zeros((batch, T), jnp.float32)
@@ -257,53 +263,54 @@ def init_cache(cfg, batch: int, max_len: int, window: Optional[int] = None,
     )
 
 
-def attn_decode(p, x1, cfg, cache: KVCache, *, window="cfg"):
+def attn_decode(p, x1, cfg, cache: KVCache, *, window="cfg", layer=None):
     """Single-token decode. x1: [B, 1, D]. Returns (out [B,1,D], cache).
 
-    ``cache.pos`` may be a scalar (all rows at the same fill level — the
-    classic batched path) or a per-row [B] vector (slot-cache serving,
-    DESIGN.md §6): each row then writes its K/V at its own position and
-    masks to its own valid length.
+    ``layer``: with an index, ``cache.k``/``v`` (and int8 scales) are the
+    layer-stacked pool [L, B, T, Hkv*dh] carried through the layer scan,
+    and this step writes its one new row per slot at (layer, b, pos_b)
+    in place and hands the kernel the pool and the index: nothing the
+    size of a layer's cache is sliced or copied. Without one, the cache
+    is one layer's [B, T, Hkv*dh], run as a pool of one layer.
+
+    ``cache.pos`` (this layer's fill level) may be a scalar (all rows at
+    the same fill level — the classic batched path) or a per-row [B]
+    vector (slot-cache serving, DESIGN.md §6): each row then writes its
+    K/V at its own position and masks to its own valid length.
     """
+    if layer is None:
+        pooled = jax.tree.map(lambda x: x[None], cache._replace(pos=None))
+        out, new = attn_decode(p, x1, cfg, pooled._replace(pos=cache.pos),
+                               window=window, layer=0)
+        return out, jax.tree.map(lambda x: x[0], new._replace(pos=None)
+                                 )._replace(pos=new.pos)
     window = cfg.sliding_window if window == "cfg" else window
     pos = cache.pos
-    per_row = getattr(pos, "ndim", 0) > 0
-    if per_row:
-        positions = pos[:, None]
-    else:
-        positions = pos[None, None] * jnp.ones((x1.shape[0], 1), jnp.int32)
+    B = x1.shape[0]
+    positions = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (B, 1))
     q, k, v = _qkv(p, x1, cfg, positions)
-    T = cache.k.shape[1]
+    T = cache.k.shape[2]
     slot = jnp.mod(pos, T) if window else jnp.minimum(pos, T - 1)
+    rows = (layer, jnp.arange(B), jnp.broadcast_to(slot, (B,)))
     kscale, vscale = cache.k_scale, cache.v_scale
     with named_span("decode.kv_cache"):
         # quantize the fresh K/V row once, at write time (no-op cast when
-        # the cache dtype matches compute_dtype)
+        # the cache dtype matches compute_dtype), and scatter it into the
+        # carried pool: one [Hkv*dh] row per slot, in place
         k, ks1 = quantize_kv(k, cache.k.dtype)
         v, vs1 = quantize_kv(v, cache.v.dtype)
-        if per_row:
-            upd = jax.vmap(
-                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
-            ck = upd(cache.k, k, slot)
-            cv = upd(cache.v, v, slot)
-            if ks1 is not None:
-                upd1 = jax.vmap(
-                    lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s,)))
-                kscale = upd1(kscale, ks1, slot)
-                vscale = upd1(vscale, vs1, slot)
-        else:
-            ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache.v, v, (0, slot, 0, 0))
-            if ks1 is not None:
-                kscale = jax.lax.dynamic_update_slice(kscale, ks1, (0, slot))
-                vscale = jax.lax.dynamic_update_slice(vscale, vs1, (0, slot))
+        ck = cache.k.at[rows].set(k.reshape(B, -1))
+        cv = cache.v.at[rows].set(v.reshape(B, -1))
+        if ks1 is not None:
+            kscale = kscale.at[rows].set(ks1[:, 0])
+            vscale = vscale.at[rows].set(vs1[:, 0])
     # Ring buffer (window set): all T slots valid once pos >= T; slot
     # positions don't matter for masking beyond validity (window == ring
     # size). Linear cache: the first pos+1 slots are valid.
     kv_len = jnp.minimum(pos + 1, T) if window else pos + 1
     from . import attn_backend as AB
 
-    out = AB.decode_attention(q, ck, cv, cfg, kv_len=kv_len,
+    out = AB.decode_attention(q, ck, cv, layer, cfg, kv_len=kv_len,
                               k_scale=kscale, v_scale=vscale)
     out = _out_proj(out, p["wo"])
     return out, KVCache(k=ck, v=cv, pos=pos + 1,
@@ -317,7 +324,7 @@ def cross_attn_decode(p, x1, cfg, cross_kv: KVCache):
     q = _proj(x1, p["wq"])
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-    out = AB.decode_attention(q, cross_kv.k, cross_kv.v, cfg)
+    out = AB.decode_attention(q, cross_kv.k[None], cross_kv.v[None], 0, cfg)
     return _out_proj(out, p["wo"])
 
 
@@ -327,4 +334,6 @@ def make_cross_cache(p, enc_out, cfg):
     v = jnp.einsum("btd,dhk->bthk", enc_out, p["wv"])
     if cfg.qk_norm:
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    return KVCache(k=k, v=v, pos=jnp.asarray(enc_out.shape[1], jnp.int32))
+    B, F = k.shape[:2]
+    return KVCache(k=k.reshape(B, F, -1), v=v.reshape(B, F, -1),
+                   pos=jnp.asarray(F, jnp.int32))

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 BACKENDS = ("auto", "jnp", "flash")
 
 __all__ = ["BACKENDS", "resolve_backend", "full_attention",
-           "decode_attention"]
+           "decode_reads_pool", "decode_attention"]
 
 
 def _partitioned() -> bool:
@@ -121,30 +121,61 @@ def full_attention(q, k, v, cfg, *, causal, window, q_offset=0, kv_len=None):
                  q_offset=q_offset, kv_len=kv_len)
 
 
-def decode_attention(q, k, v, cfg, *, kv_len=None, k_scale=None,
+def decode_reads_pool(cfg, T: int) -> bool:
+    """Whether decode attention over a cache of length ``T`` reads its
+    layer straight from the stacked KV pool: when the call resolves to
+    the kernel and T sits on the kernel's kv tile. Otherwise the layer
+    is copied out of the pool first — the jnp reference always, the
+    kernel to pad a cache off its tile. :func:`decode_attention` routes
+    by it, and the engine's ``serve.decode_kv_inplace`` gauge reports
+    it; like :func:`resolve_backend` it reads the ambient mesh."""
+    if resolve_backend(getattr(cfg, "attn_backend", "auto"),
+                       decode=True) != "flash":
+        return False
+    from ..kernels.decode_attention import kv_tile
+
+    return kv_tile(T)[1] == 0
+
+
+def decode_attention(q, k, v, layer, cfg, *, kv_len=None, k_scale=None,
                      v_scale=None):
-    """Single-query cached attention [B,1,H,dh] x [B,T,Hkv,dh].
+    """Single-query cached attention [B,1,H,dh] over layer ``layer`` of
+    the KV pool [L,B,T,Hkv*dh] (one layer's cache is a pool of one
+    layer: ``k[None]``, layer 0).
 
     The decode hot loop. ``kv_len``: scalar or per-row [B] valid cache
     length (slot serving); ring caches mask by validity only, so both
-    cache geometries take the same kernel (DESIGN.md §6/§8).
+    cache geometries take the same kernel (DESIGN.md §6/§8). Where
+    :func:`decode_reads_pool` holds, the kernel reads the layer straight
+    from the pool; elsewhere the layer is taken out of the pool first (a
+    copy, on paths the serve cell does not run).
 
-    ``k_scale``/``v_scale``: per-(row, position) [B, T] f32 dequant
-    scales of an int8 KV cache (DESIGN.md §12). The flash kernel fuses
-    the dequant into its K/V block loads; the jnp reference dequantizes
-    eagerly before ``mha``. bf16 caches carry no scales — both paths
-    already upcast at read.
+    ``k_scale``/``v_scale``: per-(layer, row, position) [L, B, T] f32
+    dequant scales of an int8 KV pool (DESIGN.md §12). The flash kernel
+    fuses the dequant into its K/V block loads; the jnp reference
+    dequantizes eagerly before ``mha``. bf16 caches carry no scales —
+    both paths already upcast at read.
     """
     from . import attention as A
 
+    B, T, dh = k.shape[1], k.shape[2], q.shape[-1]
+    if not decode_reads_pool(cfg, T):
+        # the layer, as a pool of one layer
+        k, v, k_scale, v_scale = (
+            None if x is None else jax.lax.dynamic_slice_in_dim(x, layer, 1)
+            for x in (k, v, k_scale, v_scale))
+        layer = 0
     backend = getattr(cfg, "attn_backend", "auto")
     if resolve_backend(backend, decode=True) == "flash":
         from ..kernels.decode_attention import decode_attention as _da
 
-        return _da(q, k, v, kv_len=kv_len, k_scale=k_scale, v_scale=v_scale)
+        return _da(q, k, v, layer, kv_len=kv_len, k_scale=k_scale,
+                   v_scale=v_scale)
+    k = k[0].reshape(B, T, -1, dh)
+    v = v[0].reshape(B, T, -1, dh)
     if k_scale is not None:
-        k = k.astype(jnp.float32) * k_scale[:, :, None, None]
-        v = v.astype(jnp.float32) * v_scale[:, :, None, None]
+        k = k.astype(jnp.float32) * k_scale[0][:, :, None, None]
+        v = v.astype(jnp.float32) * v_scale[0][:, :, None, None]
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
     return A.mha(q, k, v, causal=False, window=None, chunk=1, kv_len=kv_len)

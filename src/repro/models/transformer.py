@@ -62,8 +62,9 @@ def layer_forward(p, h, cfg, *, positions, window="cfg", make_cache=False,
     return h + ffn_out, cache, aux
 
 
-def layer_decode(p, h, cfg, cache, *, window="cfg"):
-    """Single-token layer. Returns (h, new_cache, aux)."""
+def layer_decode(p, h, cfg, cache, *, window="cfg", layer=None):
+    """Single-token layer. Returns (h, new_cache, aux). ``layer``: index
+    into a layer-stacked KV pool (``attention.attn_decode``)."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "ssm":
         out, cache = M.mamba2_decode(
@@ -71,7 +72,7 @@ def layer_decode(p, h, cfg, cache, *, window="cfg"):
         return h + out, cache, aux
     attn_out, cache = A.attn_decode(
         p["attn"], rmsnorm(h, p["norm_attn"], cfg.norm_eps), cfg, cache,
-        window=window)
+        window=window, layer=layer)
     h = h + attn_out
     hn = rmsnorm(h, p["norm_ffn"], cfg.norm_eps)
     if cfg.family == "moe":
@@ -183,17 +184,37 @@ def init_cache(cfg, batch_size: int, max_len: int, window="cfg"):
 
 
 def decode_step(p, cfg, caches, token, *, window="cfg"):
-    """One decode step. token: [B] int32. Returns (logits [B,V], caches)."""
+    """One decode step. token: [B] int32. Returns (logits [B,V], caches).
+
+    Attention families carry the K/V pool [L, B, T, Hkv*dh] through the
+    layer scan: each layer writes its new rows into the carried pool in
+    place and reads its keys from it by index, so no layer's cache is
+    sliced out of the stack or written back (DESIGN.md §6/§8). The
+    per-layer fill levels and SSM states are small and ride as scan xs.
+    """
     h = _embed_tokens(p, cfg, token[:, None])
+    aux0 = jnp.zeros((), jnp.float32)
+    if cfg.family == "ssm":
+        def body(carry, lp_cache):
+            h, aux = carry
+            lp, cache = lp_cache
+            h, new_cache, a = layer_decode(lp, h, cfg, cache, window=window)
+            return (h, aux + a), new_cache
 
-    def body(carry, lp_cache):
-        h, aux = carry
-        lp, cache = lp_cache
-        h, new_cache, a = layer_decode(lp, h, cfg, cache, window=window)
-        return (h, aux + a), new_cache
+        (h, _), new_caches = jax.lax.scan(body, (h, aux0),
+                                          (p["layers"], caches))
+    else:
+        def body(carry, xs):
+            h, aux, pool = carry
+            lp, layer, pos = xs
+            h, pool, a = layer_decode(lp, h, cfg, pool._replace(pos=pos),
+                                      window=window, layer=layer)
+            return (h, aux + a, pool._replace(pos=None)), pool.pos
 
-    (h, _), new_caches = jax.lax.scan(
-        body, (h, jnp.zeros((), jnp.float32)), (p["layers"], caches))
+        (h, _, pool), pos = jax.lax.scan(
+            body, (h, aux0, caches._replace(pos=None)),
+            (p["layers"], jnp.arange(cfg.n_layers), caches.pos))
+        new_caches = pool._replace(pos=pos)
     h = rmsnorm(h, p["norm_f"], cfg.norm_eps)
     return unembed(p, cfg, h)[:, 0], new_caches
 

@@ -125,8 +125,8 @@ def init_cache(cfg, batch_size: int, max_len: int, window=None):
     dtc = jnp.dtype(cfg.compute_dtype)
     F = cfg.encoder.n_frames
     cross1 = A.KVCache(
-        k=jnp.zeros((batch_size, F, cfg.n_kv_heads, cfg.head_dim), dtc),
-        v=jnp.zeros((batch_size, F, cfg.n_kv_heads, cfg.head_dim), dtc),
+        k=jnp.zeros((batch_size, F, cfg.n_kv_heads * cfg.head_dim), dtc),
+        v=jnp.zeros((batch_size, F, cfg.n_kv_heads * cfg.head_dim), dtc),
         pos=jnp.asarray(F, jnp.int32),
     )
     L = cfg.n_layers

@@ -96,6 +96,11 @@ METRICS = (
     MetricInfo("serve.kv_bytes_per_slot", "gauge", "bytes",
                "KV-cache HBM bytes one pool slot costs (quantization "
                "scales and robust replica stacking included)."),
+    MetricInfo("serve.decode_kv_inplace", "gauge", "flag",
+               "1 when decode attention reads every K/V cache of the "
+               "pool in place (attn_backend.decode_reads_pool: the "
+               "kernel, cache length on its kv tile), 0 when it copies "
+               "the layer out or the pool holds no K/V."),
     # -- robust aggregation diagnostics (train path) ------------------------
     MetricInfo("agg.alpha_hat", "gauge", "fraction",
                "Online effective-alpha estimate: fraction of workers "

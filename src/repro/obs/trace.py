@@ -26,8 +26,9 @@ one admission, holding ``serve.prefill`` (``prompt_len``),
 
 Device scopes: ``train.grad``, ``rrs.aggregate`` (holding
 ``rrs.all_to_all``) and ``train.optimizer`` in the train step;
-``decode.kv_cache`` around the per-row cache write and the cache's
-relayout ahead of the decode-attention kernel;
+``decode.kv_cache`` around the in-place per-row write into the K/V
+pool (and, for a cache length off the decode kernel's kv tile only, the
+padded copy of the layer);
 ``kernels.aggregate``, ``kernels.aggregate_sample``,
 ``kernels.decode_attention``, ``serve.decode_scan`` and
 ``consensus.round_loop``.

@@ -14,8 +14,9 @@ positionless state and need no conversion.
 Batch-dim discovery is structural, not name-based: the pool constructor
 is probed with ``eval_shape`` at two slot counts and the dim that
 changes is the slot dim (``slot_dims``). This keeps the pool agnostic to
-cache layouts — transformer ``[L, B, T, H, dh]``, hybrid grouped
-``[G, every, B, ...]``, whisper cross ``[L, B, F, H, dh]``, and the
+cache layouts — the transformer K/V pool ``[L, B, T, Hkv*dh]`` (int8
+scales ``[L, B, T]``), hybrid grouped ``[G, every, B, ...]``, whisper
+cross ``[L, B, F, Hkv*dh]``, and the
 replica-stacked trees of the robust path ``[m, L, B, ...]`` all work
 through the same code.
 """

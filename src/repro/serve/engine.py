@@ -24,7 +24,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models import attn_backend as AB
 from ..models import model as M
+from ..models.attention import KVCache
 from ..obs.trace import named_span, trace_span
 from . import cache as C
 from . import robust as R
@@ -131,6 +133,16 @@ class ServeEngine:
             obs.gauge("serve.kv_bytes_per_slot",
                       float(C.kv_bytes_per_slot(self._pool_caches,
                                                 self.n_slots)))
+            # the decode path is fixed at trace time: 1 when decode
+            # attention reads every K/V cache of the pool in place
+            # (AB.decode_reads_pool), 0 when it copies the layer out or
+            # the pool holds no K/V
+            kv = [c for c in jax.tree.leaves(
+                jax.eval_shape(lambda: self._pool_caches(1)),
+                is_leaf=lambda x: isinstance(x, KVCache))
+                if isinstance(c, KVCache)]
+            obs.gauge("serve.decode_kv_inplace", float(bool(kv) and all(
+                AB.decode_reads_pool(cfg, c.k.shape[-2]) for c in kv)))
         if self._replicated:
             # batch-dim indices of the UNSTACKED pool tree: the replica
             # dim the probe saw at axis 0 shifts every slot dim by one.

@@ -31,6 +31,20 @@ def _qkv(key, B, H, Hkv, dh, T, dtype=jnp.float32):
     return q, k, v
 
 
+def _pool(x):
+    """One layer's [B, T, Hkv, dh] K/V (or [B, T] scales) as the
+    kernel's pool of one layer [1, B, T, Hkv*dh] ([1, B, T])."""
+    return x.reshape((1,) + x.shape[:2] + (-1,)) if x.ndim == 4 else x[None]
+
+
+def _da(q, k, v, **kw):
+    """The kernel on one layer's cache in the mha layout."""
+    for s in ("k_scale", "v_scale"):
+        if kw.get(s) is not None:
+            kw[s] = _pool(kw[s])
+    return decode_attention(q, _pool(k), _pool(v), 0, **kw)
+
+
 # GQA 1:1 and 4:1, plus starcoder2's 36 heads (Hkv=4 -> group of 9)
 @pytest.mark.parametrize("H,Hkv", [(4, 4), (8, 2), (36, 4)])
 @pytest.mark.parametrize("kv_len", ["none", "scalar", "per_row"])
@@ -39,7 +53,7 @@ def test_decode_kernel_matches_mha(H, Hkv, kv_len):
     q, k, v = _qkv(jax.random.PRNGKey(H * 100 + Hkv), B, H, Hkv, dh, T)
     lens = {"none": None, "scalar": jnp.asarray(37),
             "per_row": jnp.asarray([1, 42, 100])}[kv_len]
-    got = decode_attention(q, k, v, kv_len=lens, interpret=True)
+    got = _da(q, k, v, kv_len=lens, interpret=True)
     want = mha(q, k, v, causal=False, window=None, chunk=1, kv_len=lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -51,7 +65,7 @@ def test_decode_kernel_tile_invariance(blk_k):
     beyond T rides the same validity mask as kv_len)."""
     q, k, v = _qkv(jax.random.PRNGKey(0), 2, 8, 2, 64, 200)
     lens = jnp.asarray([150, 200])
-    got = decode_attention(q, k, v, kv_len=lens, blk_k=blk_k, interpret=True)
+    got = _da(q, k, v, kv_len=lens, blk_k=blk_k, interpret=True)
     want = mha(q, k, v, causal=False, window=None, chunk=1, kv_len=lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -60,7 +74,7 @@ def test_decode_kernel_tile_invariance(blk_k):
 def test_decode_kernel_bf16():
     q, k, v = _qkv(jax.random.PRNGKey(1), 2, 8, 2, 64, 128, jnp.bfloat16)
     lens = jnp.asarray([77, 128])
-    got = decode_attention(q, k, v, kv_len=lens, interpret=True)
+    got = _da(q, k, v, kv_len=lens, interpret=True)
     want = mha(q, k, v, causal=False, window=None, chunk=1, kv_len=lens)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -71,7 +85,7 @@ def test_decode_kernel_bf16():
 def test_decode_kernel_rejects_multi_query():
     q, k, v = _qkv(jax.random.PRNGKey(2), 1, 4, 4, 32, 16)
     with pytest.raises(ValueError, match="single-query"):
-        decode_attention(jnp.concatenate([q, q], axis=1), k, v)
+        _da(jnp.concatenate([q, q], axis=1), k, v)
 
 
 # ------------------------------------------------------- model-level decode
@@ -203,8 +217,8 @@ def test_decode_kernel_int8_fused_dequant():
     kd = kq.astype(jnp.float32) * ks[:, :, None, None]
     vd = vq.astype(jnp.float32) * vs[:, :, None, None]
     want = mha(q, kd, vd, causal=False, window=None, chunk=1, kv_len=lens)
-    got = decode_attention(q, kq, vq, kv_len=lens, interpret=True,
-                           k_scale=ks, v_scale=vs)
+    got = _da(q, kq, vq, kv_len=lens, interpret=True, k_scale=ks,
+              v_scale=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     # within quantization tolerance of the unquantized attention
@@ -220,16 +234,15 @@ def test_decode_kernel_batch_tiling(blk_b):
     q, k, v = _qkv(jax.random.PRNGKey(4), B, H, Hkv, dh, T)
     lens = jnp.asarray([5, 48, 20])
     want = mha(q, k, v, causal=False, window=None, chunk=1, kv_len=lens)
-    got = decode_attention(q, k, v, kv_len=lens, interpret=True,
-                           blk_b=blk_b, blk_k=16)
+    got = _da(q, k, v, kv_len=lens, interpret=True, blk_b=blk_b, blk_k=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     kq, ks = quantize_kv(k, jnp.int8)
     vq, vs = quantize_kv(v, jnp.int8)
-    got8 = decode_attention(q, kq, vq, kv_len=lens, interpret=True,
-                            blk_b=blk_b, blk_k=16, k_scale=ks, v_scale=vs)
-    base8 = decode_attention(q, kq, vq, kv_len=lens, interpret=True,
-                             k_scale=ks, v_scale=vs)
+    got8 = _da(q, kq, vq, kv_len=lens, interpret=True, blk_b=blk_b,
+               blk_k=16, k_scale=ks, v_scale=vs)
+    base8 = _da(q, kq, vq, kv_len=lens, interpret=True, k_scale=ks,
+                v_scale=vs)
     np.testing.assert_allclose(np.asarray(got8), np.asarray(base8),
                                rtol=1e-5, atol=1e-5)
 
@@ -253,18 +266,64 @@ def test_decode_kernel_narrow_layout(kv):
     else:
         kd, vd = k, v
     want = mha(q, kd, vd, causal=False, window=None, chunk=1, kv_len=lens)
-    got = _decode_grouped(q[:, 0].reshape(B, Hkv, H // Hkv, dh), k, v, lens,
-                          ks, vs, blk_k=16, blk_b=B, interpret=True,
-                          narrow=True)
+    got = _decode_grouped(q[:, 0].reshape(B, Hkv, H // Hkv, dh), _pool(k),
+                          _pool(v), 0, lens, ks, vs, blk_k=16, blk_b=B,
+                          interpret=True, narrow=True)
     np.testing.assert_allclose(np.asarray(got.reshape(want.shape)),
                                np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("narrow", [True, False])
+@pytest.mark.parametrize("kv", ["bfloat16", "int8"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_decode_kernel_pool_matches_layer_slice(narrow, kv, layer):
+    """The kernel reading layer ``layer`` of a 3-layer pool by index
+    equals, bit for bit, the same kernel run on that layer sliced out
+    (a pool of one layer), in both layouts, bf16 and int8 with scales,
+    with per-row lengths from 1 to T."""
+    from repro.kernels.decode_attention import _decode_grouped
+
+    L, B, H, Hkv, dh, T = 3, 4, 8, 2, 32, 64
+    ks_ = jax.random.split(jax.random.PRNGKey(7 + layer), 3)
+    q = jax.random.normal(ks_[0], (B, Hkv, H // Hkv, dh), jnp.bfloat16)
+    kf = jax.random.normal(ks_[1], (L, B, T, Hkv, dh), jnp.float32)
+    vf = jax.random.normal(ks_[2], (L, B, T, Hkv, dh), jnp.float32)
+    lens = jnp.asarray([1, T, 17, 40], jnp.int32)
+    if kv == "int8":
+        (k, ksc), (v, vsc) = (jax.vmap(lambda x: quantize_kv(x, jnp.int8))(x)
+                              for x in (kf, vf))
+    else:
+        k, v = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
+        ksc = vsc = None
+    k, v = k.reshape(L, B, T, -1), v.reshape(L, B, T, -1)
+
+    def one(x):  # layer `layer` of the pool, as a pool of one layer
+        return None if x is None else x[layer][None]
+
+    kw = dict(blk_k=16, blk_b=B, interpret=True, narrow=narrow)
+    got = _decode_grouped(q, k, v, jnp.asarray(layer, jnp.int32), lens,
+                          *(None if x is None else x[layer]
+                            for x in (ksc, vsc)), **kw)
+    want = _decode_grouped(q, one(k), one(v), 0, lens,
+                           *(None if x is None else x[layer]
+                             for x in (ksc, vsc)), **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    # the public entry takes the layer's int8 scales from the pool's
+    q1 = q.reshape(B, 1, H, dh)
+    kw = dict(kv_len=lens, blk_k=16, interpret=True)
+    ent = decode_attention(q1, k, v, layer, k_scale=ksc, v_scale=vsc, **kw)
+    sliced = decode_attention(q1, one(k), one(v), 0, k_scale=one(ksc),
+                              v_scale=one(vsc), **kw)
+    np.testing.assert_array_equal(np.asarray(ent, np.float32),
+                                  np.asarray(sliced, np.float32))
 
 
 def test_decode_kernel_scale_validation():
     q, k, v = _qkv(jax.random.PRNGKey(5), 2, 4, 2, 32, 16)
     ks = jnp.ones((2, 16), jnp.float32)
     with pytest.raises(ValueError, match="scale"):
-        decode_attention(q, k, v, k_scale=ks, interpret=True)
+        _da(q, k, v, k_scale=ks, interpret=True)
 
 
 @pytest.mark.parametrize("kv,tol", [("bfloat16", 2e-2), ("int8", 0.25)])

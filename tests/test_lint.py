@@ -247,6 +247,21 @@ def test_rl005_true_positive_subscript_and_call():
     assert ids(fs) == ["RL005", "RL005"]
 
 
+def test_rl005_scalar_prefetch_reads():
+    """A subscript of the map's own scalar-prefetch argument is the
+    Pallas TPU way to pick a block by data; a call or a captured table
+    inside the subscript is still flagged."""
+    fs = run("""
+        from jax.experimental import pallas as pl
+        def f(table, fn):
+            ok = pl.BlockSpec((1, 8), lambda i, j, layer: (layer[0], i, j))
+            bad = pl.BlockSpec((1, 8), lambda i, j, layer: (layer[fn(i)], j))
+            worse = pl.BlockSpec((1, 8), lambda i, j, layer: (table[i], j))
+            return ok, bad, worse
+        """)
+    assert ids(fs) == ["RL005", "RL005"]
+
+
 def test_rl005_true_negative_pure_arithmetic():
     fs = run("""
         from jax.experimental import pallas as pl

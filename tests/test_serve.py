@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get as get_arch
+from repro.models import attn_backend as AB
 from repro.models import model as Mo
 from repro.serve import (Request, RobustDecodeConfig, Sampling, Scheduler,
                          ServeEngine, replica_mask, robust_logits)
@@ -320,6 +321,7 @@ def test_pool_specs_shard_and_decode():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get as get_arch
 from repro.dist import ctx as CTX, sharding as S
+from repro.models import attn_backend as AB
 from repro.models import model as Mo
 from repro.serve import Request, Scheduler, ServeEngine
 from repro.serve import cache as C
@@ -570,3 +572,93 @@ def test_shared_replica_compute_pool_identity(dense):
 
     a, b = run(True), run(False)
     assert a == b
+
+
+# ---------------------------------------------------------------------------
+# The decode loop reads and writes the stacked KV pool in place
+# ---------------------------------------------------------------------------
+
+def _per_layer_decode_step(params, cfg, caches, tok):
+    """Reference decode step: each layer's cache sliced out of the stack,
+    decoded as one layer's cache, and stacked back."""
+    from repro.models import transformer as Tr
+    from repro.models.layers import rmsnorm
+
+    h = Tr._embed_tokens(params, cfg, tok[:, None])
+    new = []
+    for l in range(cfg.n_layers):
+        lp, c = jax.tree.map(lambda x: x[l], (params["layers"], caches))
+        h, c, _ = Tr.layer_decode(lp, h, cfg, c)
+        new.append(c)
+    h = rmsnorm(h, params["norm_f"], cfg.norm_eps)
+    return (Tr.unembed(params, cfg, h)[:, 0],
+            jax.tree.map(lambda *x: jnp.stack(x), *new))
+
+
+def test_decode_pool_in_place_matches_per_layer_reference(dense):
+    """Admissions and an eviction leave the slots at different fill
+    levels; the pool decode loop (kernel reading the pool in place) emits
+    the jnp-backend engine's tokens, and its pool, layer by layer, is the
+    one a per-layer reference decode writes."""
+    import dataclasses
+
+    cfg, params = dense
+    assert cfg.n_layers == 2
+    ref_cfg = dataclasses.replace(cfg, attn_backend="jnp")
+    ref_step = jax.jit(lambda c, t: _per_layer_decode_step(params, ref_cfg,
+                                                           c, t))
+    engines = {b: ServeEngine(cfg, params, max_len=32, n_slots=3,
+                              attn_backend=b) for b in ("auto", "jnp")}
+    assert AB.decode_reads_pool(engines["auto"].cfg, 32)
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(0, cfg.vocab, size=(n,)) for n in (5, 9, 3, 6)]
+    # (admit {slot: prompt}, evict [slots], decode n steps)
+    plan = [({0: 0, 1: 1}, [], 3), ({0: 2, 2: 3}, [0], 4)]
+    out = {}
+    for name in ("auto", "jnp", "reference"):
+        eng = engines["jnp" if name == "reference" else name]
+        pool, cur, toks = eng.make_pool(), np.zeros(3, np.int32), []
+        for admits, evicts, n in plan:
+            for s in evicts:
+                pool = eng.evict(pool, s)
+            for s, i in admits.items():
+                pool, cur[s] = eng.admit(pool, s, {
+                    "tokens": jnp.asarray(prompts[i])[None]})
+            if name == "reference":
+                caches, t = pool.caches, jnp.asarray(cur)
+                for _ in range(n):
+                    logits, caches = ref_step(caches, t)
+                    t = jnp.argmax(logits, -1).astype(jnp.int32)
+                    toks.append(np.asarray(t))
+                pool = pool._replace(caches=caches)
+            else:
+                pool, blk = eng.decode_pool(pool, cur, n)
+                toks += list(np.asarray(blk))
+            cur = np.array(toks[-1], np.int32)
+        out[name] = (np.stack(toks), pool.caches)
+    got_toks, got = out["auto"]
+    for name in ("jnp", "reference"):
+        np.testing.assert_array_equal(got_toks, out[name][0])
+    want = out["reference"][1]
+    np.testing.assert_array_equal(np.asarray(got.pos), np.asarray(want.pos))
+    assert got.k.shape == (cfg.n_layers, 3, 32,
+                           cfg.n_kv_heads * cfg.head_dim)
+    for l in range(cfg.n_layers):
+        for a, b in ((got.k, want.k), (got.v, want.v)):
+            np.testing.assert_allclose(np.asarray(a[l]), np.asarray(b[l]),
+                                       rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend,window,flag", [
+    ("auto", "cfg", 1.0), ("jnp", "cfg", 0.0), ("auto", 20, 1.0)])
+def test_decode_kv_inplace_gauge(dense, backend, window, flag):
+    """serve.decode_kv_inplace: 1 when the decode kernel reads the pool
+    in place (a ring cache too), 0 on the jnp reference's per-layer
+    copies."""
+    from repro.obs import MetricsRegistry
+
+    cfg, params = dense
+    reg = MetricsRegistry()
+    ServeEngine(cfg, params, max_len=32, window=window, attn_backend=backend,
+                obs=reg)
+    assert reg.snapshot()["gauges"]["serve.decode_kv_inplace"] == flag

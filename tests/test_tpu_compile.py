@@ -10,6 +10,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -72,21 +74,24 @@ def test_fused_tail_compiles(one_chip, top_k, b):
 
 @pytest.mark.parametrize("kv", ["bfloat16", "int8"])
 def test_decode_attention_compiles(one_chip, kv):
+    """The kernel reads a traced layer of a two-layer pool."""
     B, T, Hkv, G, dh = (DECODE[k] for k in ("B", "T", "Hkv", "G", "dh"))
     q = _sds(one_chip, (B, 1, Hkv * G, dh), jnp.bfloat16)
-    k = _sds(one_chip, (B, T, Hkv, dh), jnp.dtype(kv))
+    k = _sds(one_chip, (2, B, T, Hkv * dh), jnp.dtype(kv))
+    layer = _sds(one_chip, (), jnp.int32)
     lens = _sds(one_chip, (B,), jnp.int32)
     if kv == "int8":
-        s = _sds(one_chip, (B, T), jnp.float32)
+        s = _sds(one_chip, (2, B, T), jnp.float32)
         _compiles_with_kernel(
-            lambda q, k, v, l, sk, sv: decode_attention(
-                q, k, v, kv_len=l, k_scale=sk, v_scale=sv, interpret=False),
-            q, k, k, lens, s, s)
+            lambda q, k, v, i, l, sk, sv: decode_attention(
+                q, k, v, i, kv_len=l, k_scale=sk, v_scale=sv,
+                interpret=False),
+            q, k, k, layer, lens, s, s)
     else:
         _compiles_with_kernel(
-            lambda q, k, v, l: decode_attention(q, k, v, kv_len=l,
-                                                interpret=False),
-            q, k, k, lens)
+            lambda q, k, v, i, l: decode_attention(q, k, v, i, kv_len=l,
+                                                   interpret=False),
+            q, k, k, layer, lens)
 
 
 @pytest.mark.parametrize("S", [2048, 512, 100])
@@ -96,3 +101,111 @@ def test_flash_forward_compiles(one_chip, S):
     _compiles_with_kernel(
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         interpret=False), q, kv, kv)
+
+
+_HLO_INST = re.compile(
+    r"^\s*(ROOT )?%(\S+) = (.*?) ([\w-]+)\(([^)]*)\)")
+_HLO_COMP = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_HLO_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _hlo_ops(text):
+    """Optimized HLO text -> {name: (elements, opcode, operands, op_name,
+    root)} for every instruction, fused computations included.
+    ``elements`` is the largest array of the result (a tuple's largest
+    element); ``root`` is, for a fusion, the root instruction of the
+    computation it calls."""
+    ops, roots, calls = {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        c = _HLO_COMP.match(line)
+        if c:
+            comp = c.group(1)
+            continue
+        m = _HLO_INST.match(line)
+        if not m:
+            continue
+        is_root, name, result, opcode, operands = m.groups()
+        n = 0
+        for dims in _HLO_ARRAY.findall(result):
+            size = 1
+            for d in filter(None, dims.split(",")):
+                size *= int(d)
+            n = max(n, size)
+        if is_root:
+            roots[comp] = name
+        called = re.search(r"calls=%([\w.-]+)", line)
+        if called:
+            calls[name] = called.group(1)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        ops[name] = [n, opcode, re.findall(r"%([\w.-]+)", operands),
+                     op_name.group(1) if op_name else "", None]
+    for name, comp in calls.items():
+        ops[name][4] = roots.get(comp)
+    return ops
+
+
+def test_decode_loop_reads_pool_in_place(one_chip, monkeypatch):
+    """An 8-step scan of ``decode_step`` at qwen3-1.7b widths (2 layers,
+    16 slots of 2048 keys, bf16) compiled with the kernel: inside the
+    loops no op whose result (or a tuple's largest element) is as large
+    as one layer's cache, save loop plumbing that moves no data and a
+    write of one row per slot (the in-place scatter, bare or as the root
+    of a fusion): no slice, copy, relayout, transpose or async copy. The
+    entry copy of a pool the caller does not donate lies outside the
+    loops."""
+    import dataclasses
+    import sys
+
+    from repro.configs import get as get_arch
+    from repro.models import attn_backend as AB
+    from repro.models import model as M
+    from repro.serve.cache import vectorize_pos
+
+    monkeypatch.setattr(sys.modules["repro.kernels.decode_attention"],
+                        "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_arch("qwen3-1.7b"), n_layers=2)
+    B, T = 16, 2048
+    Hkv, dh = cfg.n_kv_heads, cfg.head_dim
+    assert AB.decode_reads_pool(cfg, T)
+
+    def sds(tree):
+        return jax.tree.map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = sds(jax.eval_shape(lambda: M.init(jax.random.PRNGKey(0), cfg)))
+    caches = sds(jax.eval_shape(
+        lambda: vectorize_pos(M.init_cache(cfg, B, T), B)))
+
+    def loop(params, caches, tok):
+        def body(carry, _):
+            caches, tok = carry
+            logits, caches = M.decode_step(params, cfg, caches, tok)
+            return (caches, jnp.argmax(logits, -1).astype(jnp.int32)), None
+
+        return jax.lax.scan(body, (caches, tok), None, length=8)[0]
+
+    text = jax.jit(loop).lower(params, caches,
+                               _sds(one_chip, (B,), jnp.int32)
+                               ).compile().as_text()
+    assert "tpu_custom_call" in text
+    layer_cache, row = B * T * Hkv * dh, B * Hkv * dh
+    ops = _hlo_ops(text)
+
+    def row_write(name):  # a write of at most one row per slot
+        n, opcode, operands, _, root = ops[name]
+        if opcode == "fusion":
+            return root is not None and row_write(root)
+        if opcode not in ("dynamic-update-slice", "scatter"):
+            return False
+        upd = operands[1 if opcode == "dynamic-update-slice" else 2]
+        return upd in ops and ops[upd][0] <= row
+
+    # ops that move no data: loop plumbing and aliasing views
+    plumbing = ("parameter", "get-tuple-element", "tuple", "while",
+                "bitcast")
+    bad = [(name, opcode, n, op_name)
+           for name, (n, opcode, _, op_name, _) in ops.items()
+           if "while" in op_name and n >= layer_cache
+           and opcode not in plumbing and not row_write(name)]
+    assert not bad, bad
